@@ -18,6 +18,7 @@ from repro.autotune import planner, profiler
 from repro.autotune.schedule import Schedule
 from repro.configs import base
 from repro.core import adaptive, bucketing, comm_model as cm
+from repro.launch import compile_cache
 
 
 def profile_layers(arch: str, seq_tokens: int = 4096 * 8):
@@ -52,6 +53,7 @@ def report(cfg, layers, ratios: dict, tag: str):
 
 
 def main(argv=None):
+    compile_cache.place()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3_8b")
     ap.add_argument("--schedule", default=None,

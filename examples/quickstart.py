@@ -14,6 +14,7 @@ import jax
 from repro import api
 from repro.configs import base
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.models import transformer as T
 
 P = 4          # simulated workers
@@ -21,6 +22,7 @@ STEPS = 40
 
 
 def main():
+    compile_cache.place()
     cfg = dataclasses.replace(
         base.get_smoke_config("tinyllama_1_1b"),
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128, vocab=64)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import base
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.serving import engine
 
@@ -61,6 +62,7 @@ def demo(arch: str, batch: int = 4, prompt_len: int = 24,
 
 
 def main():
+    compile_cache.place()
     demo("tinyllama_1_1b")     # full KV cache
     demo("xlstm_1_3b")         # O(1) recurrent state
     demo("jamba_v0_1_52b")     # hybrid: ring/full caches + SSM states

@@ -35,6 +35,7 @@ import numpy as np
 from repro import api
 from repro.configs import base
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as M
 from repro.stream import (DeltaCodec, RolloutGuard, ServeSession,
                           StreamPublisher, quality_probe)
@@ -44,6 +45,7 @@ TINY = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
 
 
 def main():
+    compile_cache.place()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", type=int, default=32)

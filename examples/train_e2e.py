@@ -43,6 +43,7 @@ import jax
 from repro import api
 from repro.configs import base
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as M
 
 
@@ -61,6 +62,7 @@ PRESETS = {
 
 
 def main():
+    compile_cache.place()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="100m", choices=sorted(PRESETS))
     ap.add_argument("--steps", type=int, default=300)

@@ -1,111 +1,37 @@
-"""Version-adaptive aliases for jax APIs that moved between releases.
+"""Mesh and shard_map helpers shared by library code.
 
-The repo targets the current jax API (``jax.shard_map``, ``jax.set_mesh``,
-``jax.sharding.AxisType``, ``jax.sharding.get_abstract_mesh``); the
-container pins jax 0.4.37, where the same machinery lives under
-experimental/internal names (``jax.experimental.shard_map`` with the
-``auto=`` partial-manual parameter, ``jax._src.mesh.AxisTypes`` with
-member ``User`` instead of ``Explicit``, dict-valued ``Mesh.axis_types``).
-Library code imports these five names from here instead of hard-coding
-either spelling:
+Thin aliases over the jax API with the repo's defaults: every mesh axis
+is GSPMD-``Auto`` unless a ``shard_map`` binds it, and ``shard_map`` is
+partial-manual (only ``axis_names`` are manual).
 
     from repro import compat
     compat.make_mesh / compat.set_mesh / compat.shard_map
-    compat.get_abstract_mesh / compat.auto_axis_names / compat.AxisType
+    compat.get_abstract_mesh / compat.auto_axis_names
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-_NEW_API = hasattr(jax, "set_mesh") and hasattr(jax.sharding, "AxisType")
-
-if _NEW_API:
-    from jax.sharding import AxisType
-else:
-    from jax._src.mesh import AxisTypes as AxisType  # Auto/User/Collective
-    # New jax defaults to sharding-invariant (partitionable) threefry; on
-    # 0.4.x the default False makes jitted random values depend on the
-    # output sharding (observed: params initialized under out_shardings
-    # diverge from the eager init of the same PRNGKey).  Align the
-    # semantics so init/test parity holds across versions.
-    jax.config.update("jax_threefry_partitionable", True)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
     """All-axes-Auto mesh (GSPMD-managed unless shard_map binds an axis)."""
-    if _NEW_API:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axis_shapes))
-    from jax._src import mesh as _mesh
-    base = jax.make_mesh(axis_shapes, axis_names, devices=devices)
-    return _mesh.Mesh(base.devices, base.axis_names,
-                      axis_types={AxisType.Auto: tuple(axis_names)})
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes))
 
 
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Ambient-mesh context: makes bare-PartitionSpec sharding constraints
-    resolve against ``mesh`` and ``get_abstract_mesh()`` see it.
-
-    On 0.4.x this intentionally does NOT flip the ``sharding_in_types``
-    config (jax's own ``jax._src.mesh.set_mesh`` does) — that mode is
-    half-built there and changes tracing semantics; the physical-mesh
-    resource env plus the abstract-mesh slot are what this repo needs.
-    """
-    if _NEW_API:
-        with jax.set_mesh(mesh):
-            yield mesh
-        return
-    from jax._src.mesh import set_abstract_mesh
-    with mesh, set_abstract_mesh(mesh.abstract_mesh):
-        yield mesh
-
-
-def get_abstract_mesh():
-    """Current abstract mesh, or a falsy placeholder outside any context."""
-    if _NEW_API:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import get_abstract_mesh as _gam
-    return _gam()
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
 
 
 def auto_axis_names(mesh_like) -> tuple:
-    """Names of the GSPMD-Auto axes of a (possibly abstract) mesh, across
-    both axis_types encodings (per-axis tuple vs {type: names} dict);
-    meshes without type info are treated as all-Auto."""
+    """Names of the GSPMD-Auto axes of a (possibly abstract) mesh; meshes
+    without type info are treated as all-Auto."""
     names = tuple(getattr(mesh_like, "axis_names", ()) or ())
     types = getattr(mesh_like, "axis_types", None)
     if types is None:
         return names
-    if isinstance(types, dict):  # jax 0.4.x
-        auto = types.get(AxisType.Auto, ())
-        auto = (auto,) if isinstance(auto, str) else tuple(auto)
-        return tuple(n for n in names if n in auto)
     return tuple(n for n, t in zip(names, types) if t == AxisType.Auto)
-
-
-def hint_sharding(x, spec):
-    """Best-effort ``with_sharding_constraint`` for partitioner *hints*
-    (activation pinning, block-row layouts).  On the new API these resolve
-    against the ambient mesh — including inside partial-manual shard_map
-    regions, where the axis-type bookkeeping builds the required
-    manual-subgroup sharding.  jax 0.4.x has no such bookkeeping and XLA
-    aborts on non-subgroup shardings inside manual computations
-    (``Check failed: sharding.IsManualSubgroup()``), so there the hints
-    are dropped: layouts are then GSPMD's choice, which costs performance
-    on real accelerators but never correctness."""
-    if _NEW_API:
-        return jax.lax.with_sharding_constraint(x, spec)
-    return x
-
-
-def axis_size(axis_name):
-    """Size of a shard_map-bound mesh axis, from inside the manual region."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
@@ -114,18 +40,6 @@ def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
     other mesh axis stays GSPMD-auto."""
     manual = (set(axis_names) if axis_names is not None
               else set(mesh.axis_names))
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual,
-                             check_vma=check_vma)
-    # jax 0.4.x: jax.experimental.shard_map supports an ``auto=`` set, but
-    # its jaxlib SPMD partitioner aborts on any collective inside a
-    # partial-manual computation (Check failed: IsManualSubgroup).  Bind
-    # EVERY axis manual instead: in/out specs only ever mention the
-    # caller's manual axes, so the would-be-auto axes fall back to
-    # replication — numerically identical, trading the GSPMD tensor-
-    # parallel sharding inside the region for replicated compute.  Real
-    # TP inside shard_map needs the new-API partial-auto path.
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=frozenset())
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=manual,
+                         check_vma=check_vma)

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import compressors as C
 
 
@@ -141,14 +140,14 @@ def _psum_mean(x, axis_names):
     s = jax.lax.psum(x, axis_names)
     n = 1
     for a in axis_names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return s / n
 
 
 def _axis_prod(axis_names) -> jax.Array:
     n = 1
     for a in axis_names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -156,7 +155,7 @@ def _worker_index(axis_names) -> jax.Array:
     """Linearized worker index over the manual axes (0 outside shard_map)."""
     idx = jnp.int32(0)
     for a in axis_names:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -495,7 +494,7 @@ class BlockLAGSExchange:
             return rows
         from jax.sharding import PartitionSpec as P
         ax = self.row_axes if len(self.row_axes) > 1 else self.row_axes[0]
-        return compat.hint_sharding(rows, P(ax, None))
+        return jax.lax.with_sharding_constraint(rows, P(ax, None))
 
     # -- per-leaf geometry --------------------------------------------------
     def _geom(self, size: int, k: int):
@@ -515,9 +514,6 @@ class BlockLAGSExchange:
         partition, so the partitioner ALL-GATHERS the full row matrix
         (measured 27 GiB/dev on llama3-8b).  Max/argmax/where are
         elementwise/reduce ops along the unsharded dim -> fully local."""
-        if self.use_kernel:
-            from repro.kernels import ops as kops
-            return kops.block_topk(rows, k_b)
         if k_b > 32:
             _, local = jax.lax.top_k(jnp.abs(rows), k_b)
             vals = jnp.take_along_axis(rows, local, axis=1)
@@ -552,7 +548,8 @@ class BlockLAGSExchange:
                 jnp.pad(u_flat, (0, pad)).reshape(n_blocks, bs))
             e_rows = self._pin_rows(
                 jnp.pad(e_flat, (0, pad)).reshape(n_blocks, bs))
-            return kops.ef_select_pack_rows(g_rows, e_rows, 1.0, None, k_b)
+            return kops.ef_select_pack_rows(g_rows, e_rows, 1.0, None, k_b,
+                                            row_axes=self.row_axes)
         acc = e_flat + u_flat.astype(e_flat.dtype)
         rows = self._pin_rows(jnp.pad(acc, (0, pad)).reshape(n_blocks, bs))
         vals, local = self._select_rows(rows, k_b)

@@ -1,15 +1,20 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute under ``interpret=True`` —
-the kernel body runs in Python per grid step, validating the exact TPU
-program.  On a real TPU backend set ``interpret=False`` (auto-detected).
+On a TPU backend the kernels compile through Mosaic; on any other
+backend (CPU tests) they run under ``interpret=True`` — the kernel body
+runs in Python per grid step, validating the same program.
+
+Mosaic kernels cannot be partitioned by GSPMD, so every wrapper runs its
+kernel per device (:func:`_per_device`): under a mesh with auto axes the
+call is wrapped in a ``shard_map`` that is manual over every mesh axis.
 """
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.kernels import block_topk as _bt
 from repro.kernels import ef_sparsify as _ef
@@ -19,15 +24,52 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _per_device(kernel, rows, scalars=(), row_axes=()):
+    """``kernel(*rows, *scalars)`` on each device's share of ``rows``.
+
+    Outside a mesh, or where every mesh axis is already manual, this is
+    the plain call.  Otherwise the call runs in a ``shard_map`` manual
+    over all mesh axes: the leading (row) dim of ``rows`` and of every
+    output is split over those of ``row_axes`` that are still auto
+    (padded to a multiple of their size), and everything else is
+    replicated.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = [a for a, t in zip(mesh.axis_names, mesh.axis_types)
+            if t != AxisType.Manual]
+    if not auto:
+        return kernel(*rows, *scalars)
+    split = tuple(a for a in row_axes if a in auto)
+    m = math.prod(mesh.shape[a] for a in split)
+    n = rows[0].shape[0]
+    pad = -n % m
+    if pad:
+        rows = [jnp.pad(r, ((0, pad),) + ((0, 0),) * (r.ndim - 1))
+                for r in rows]
+    spec = P(split or None)
+    out = jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(spec,) * len(rows) + (P(),) * len(scalars),
+        out_specs=spec, axis_names=set(mesh.axis_names),
+        check_vma=False)(*rows, *scalars)
+    return jax.tree.map(lambda o: o[:n], out) if pad else out
+
+
 def block_topk(blocks: jax.Array, r: int, *, tm: int = 8):
     """Per-row top-r by magnitude: (values, local int32 indices)."""
-    return _bt.block_topk_pallas(blocks, r, tm=tm, interpret=_interpret())
+    interpret = _interpret()
+    return _per_device(
+        lambda x: _bt.block_topk_pallas(x, r, tm=tm, interpret=interpret),
+        (blocks,))
 
 
 def ef_accum_sparsify(g: jax.Array, e: jax.Array, lr, thr, *, tm: int = 64):
     """Fused acc = e + lr*g; selected = acc·[|acc|≥thr]; residual = acc−sel."""
-    return _ef.ef_accum_sparsify_pallas(g, e, lr, thr, tm=tm,
-                                        interpret=_interpret())
+    interpret = _interpret()
+    return _per_device(
+        lambda gg, ee, lr_, thr_: _ef.ef_accum_sparsify_pallas(
+            gg, ee, lr_, thr_, tm=tm, interpret=interpret),
+        (g, e), (jnp.asarray(lr, jnp.float32), jnp.asarray(thr, jnp.float32)))
 
 
 def hier_topk_threshold(x: jax.Array, k: int, *, block_size: int = 4096,
@@ -60,7 +102,7 @@ def hier_topk_threshold(x: jax.Array, k: int, *, block_size: int = 4096,
 
 
 def ef_select_pack_rows(g_rows: jax.Array, e_rows: jax.Array, lr, thr,
-                        k: int, *, tm: int = 8):
+                        k: int, *, tm: int = 8, row_axes: tuple = ()):
     """Fused EF accumulate + per-block top-k + payload pack on a block view.
 
     g_rows: (n_blocks, bs) any float; e_rows: (n_blocks, bs) f32.
@@ -68,10 +110,16 @@ def ef_select_pack_rows(g_rows: jax.Array, e_rows: jax.Array, lr, thr,
     bitwise equal selection/residual to the XLA block top-k path).
     Returns (vals (n_blocks, k) f32, local idx (n_blocks, k) int32,
     residual (n_blocks, bs) f32); ``acc = e + lr·g`` never touches HBM.
+    ``row_axes``: mesh axes the block rows are split over under a mesh.
     """
     thr_v = jnp.float32(-jnp.inf) if thr is None else thr
-    return _ef.ef_select_pack_pallas(g_rows, e_rows, lr, thr_v, k=k, tm=tm,
-                                     interpret=_interpret())
+    interpret = _interpret()
+    return _per_device(
+        lambda g, e, lr_, thr_: _ef.ef_select_pack_pallas(
+            g, e, lr_, thr_, k=k, tm=tm, interpret=interpret),
+        (g_rows, e_rows),
+        (jnp.asarray(lr, jnp.float32), jnp.asarray(thr_v, jnp.float32)),
+        row_axes)
 
 
 def _block_view(x: jax.Array, n_blocks: int, bs: int) -> jax.Array:
@@ -128,8 +176,11 @@ def ef_hier_pack(g: jax.Array, e: jax.Array, lr, k: int, *,
     r_eff = min(r, bs)
     g_rows = _block_view(g, n_blocks, bs)
     e_rows = _block_view(e, n_blocks, bs)
-    cand_vals, _ = _ef.ef_block_candidates_pallas(
-        g_rows, e_rows, lr, r=r_eff, tm=tm, interpret=_interpret())
+    interpret = _interpret()
+    cand_vals, _ = _per_device(
+        lambda gg, ee, lr_: _ef.ef_block_candidates_pallas(
+            gg, ee, lr_, r=r_eff, tm=tm, interpret=interpret),
+        (g_rows, e_rows), (jnp.asarray(lr, jnp.float32),))
     cand_flat = cand_vals.reshape(-1)
     kk = min(k, cand_flat.shape[0])
     thr = jax.lax.top_k(jnp.abs(cand_flat), kk)[0][-1]

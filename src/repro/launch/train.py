@@ -517,7 +517,7 @@ def build_train_step(cfg, mesh, run: RunConfig):
 
                 def resh(x):
                     y = x.reshape((n_w, x.shape[0] // n_w) + x.shape[1:])
-                    return compat.hint_sharding(
+                    return jax.lax.with_sharding_constraint(
                         y, P(lead, "data", *([None] * (len(x.shape) - 1))))
                 vb = jax.tree.map(resh, batch)
                 (losses, _aux), grads = jax.vmap(

@@ -127,7 +127,7 @@ def chunked_attention(q, k, v, *, q_positions, k_positions, causal=True,
             "bhgqc,bhcd->bhgqd", p_, vj.astype(jnp.float32))
         return (m_new, l, acc), None
 
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0),
+    (m, l, acc), _ = jax.lax.scan(jax.checkpoint(body), (m0, l0, acc0),
                                   (kc, vc, kpos_c, kidx_c))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 3, 1, 2, 4).astype(q.dtype)  # B,Sq,KV,G,hd

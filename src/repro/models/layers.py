@@ -38,7 +38,7 @@ def pin_act(x: jax.Array, tp_dim: int | None = None) -> jax.Array:
         spec[tp_dim] = "model"
     if all(s is None for s in spec):
         return x
-    return compat.hint_sharding(x, P(*spec))
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:

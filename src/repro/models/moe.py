@@ -162,7 +162,7 @@ def moe_forward_grouped(p, x, *, top_k: int, activation: str = "silu",
     def pin(v, *rest):
         if not have_mesh:
             return v
-        return compat.hint_sharding(v, P(dg, *rest))
+        return jax.lax.with_sharding_constraint(v, P(dg, *rest))
 
     xt = pin(x.reshape(g, tg, d), None, None)                    # (G,Tg,D)
 
