@@ -199,6 +199,13 @@ class Session:
         with ``run.health_every > 0`` for the in-graph quantities to
         exist at all.
 
+        Each step runs under ``jax.profiler.StepTraceAnnotation("lags/
+        step", step_num=t)`` with host spans ``lags/host/data`` (the
+        ``data_fn`` call), ``lags/host/dispatch`` (the step call),
+        ``lags/host/loss_sync`` (the ``float(loss)``) and ``lags/host/
+        bookkeeping`` (the rest), so a profiler trace of the loop says
+        what the host did in each of the device's idle gaps.
+
         Returns ``(state, history)`` where ``history`` is the list of
         logged row dicts.
         """
@@ -210,6 +217,8 @@ class Session:
         from repro.checkpoint import io as ckpt
         from repro.observe import events as OE
         from repro.observe import metrics as OM
+        from repro.observe import names as ON
+        from repro.observe.trace import annotation
 
         mesh = self._need_mesh("run")
         step_fn = controller.step if controller is not None else self.step_fn
@@ -245,7 +254,6 @@ class Session:
         health_leaves: list[str] = []
         if health_every > 0:
             from repro.observe import health as OH
-            from repro.observe import names as ON
             health_leaves = OH.leaf_names(state["params"])
             m_h_delta = reg.gauge(
                 "train_health_delta",
@@ -281,15 +289,30 @@ class Session:
 
         history: list[dict] = []
         n_events = 0
+        # the predicted payload changes only when the controller swaps
+        # the plan: worked out per plan, not per step
+        n_plans = len(controller.history) if controller is not None else 0
+        comm_bytes = _step_comm_bytes(
+            controller.meta if controller is not None else self.meta,
+            state["params"])
+        span_data = ON.host_name("data")
+        span_dispatch = ON.host_name("dispatch")
+        span_sync = ON.host_name("loss_sync")
+        span_books = ON.host_name("bookkeeping")
         t_start = time.time()
         log = open(log_path, "a") if log_path else None
         try:
             with compat.set_mesh(mesh):
-                for t in range(n_steps):
+                for t, spans in _step_spans(n_steps):
                     t0 = time.perf_counter()
-                    state, metrics_out = step_fn(state, data_fn(t))
-                    loss = float(metrics_out["loss"])   # device sync
+                    with annotation(span_data):
+                        batch = data_fn(t)
+                    with annotation(span_dispatch):
+                        state, metrics_out = step_fn(state, batch)
+                    with annotation(span_sync):
+                        loss = float(metrics_out["loss"])   # device sync
                     step_s = time.perf_counter() - t0
+                    spans.enter_context(annotation(span_books))
                     row = {"step": t, "loss": loss,
                            "elapsed_s": round(time.time() - t_start, 1),
                            "step_s": step_s}
@@ -298,9 +321,12 @@ class Session:
                     m_loss.set(loss, mode=mode)
                     live_meta = (controller.meta if controller is not None
                                  else self.meta)
-                    m_comm.inc(_step_comm_bytes(live_meta,
-                                                state["params"]),
-                               mode=mode)
+                    if (controller is not None
+                            and len(controller.history) > n_plans):
+                        n_plans = len(controller.history)
+                        comm_bytes = _step_comm_bytes(live_meta,
+                                                      state["params"])
+                    m_comm.inc(comm_bytes, mode=mode)
                     waves = live_meta.get("waves")
                     if waves is not None and waves.predicted:
                         m_overlap.set(float(waves.predicted["overlap"]),
@@ -380,6 +406,22 @@ class Session:
                              meta={"arch": self.cfg.name, "mode": mode,
                                    "n_steps": int(n_steps)})
         return state, history
+
+
+def _step_spans(n_steps: int):
+    """``(t, spans)`` for each step, the body running inside the
+    ``lags/step`` profiler step annotation; ``spans`` (an ``ExitStack``)
+    holds the host spans the body opens to the end of the step."""
+    import contextlib
+
+    import jax
+
+    from repro.observe import names as ON
+    for t in range(n_steps):
+        with contextlib.ExitStack() as spans:
+            spans.enter_context(
+                jax.profiler.StepTraceAnnotation(ON.STEP, step_num=t))
+            yield t, spans
 
 
 def _step_comm_bytes(meta, params) -> int:

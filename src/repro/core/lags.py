@@ -103,7 +103,8 @@ def local_select(acc_leaf: jax.Array, k: int, compressor: C.Compressor,
 
 
 def local_select_ef(u_leaf: jax.Array, e_leaf: jax.Array, k: int,
-                    compressor: C.Compressor, key=None, **kw):
+                    compressor: C.Compressor, key=None, *, label: str = "",
+                    **kw):
     """Per-leaf EF accumulate + select, fused when the compressor can.
 
     The one selection entry point the exchanges call: a compressor with a
@@ -123,13 +124,16 @@ def local_select_ef(u_leaf: jax.Array, e_leaf: jax.Array, k: int,
     path disagree with its own eager execution.  It lands in the
     residual and the selected values, so end-to-end training agrees to
     1-ulp tolerance rather than bitwise; EF absorbs the difference.
+
+    Runs under the ``lags/select/<label>`` phase scope.
     """
-    if compressor.fused_select is not None and not compressor.needs_key:
-        vals, idx, resid = compressor.fused_select(
-            u_leaf.reshape(-1), e_leaf.reshape(-1), k, **kw)
-        return vals, idx, resid.reshape(e_leaf.shape)
-    acc = e_leaf + u_leaf.astype(e_leaf.dtype)
-    return local_select(acc, k, compressor, key=key, **kw)
+    with _phase_scope("select", label):
+        if compressor.fused_select is not None and not compressor.needs_key:
+            vals, idx, resid = compressor.fused_select(
+                u_leaf.reshape(-1), e_leaf.reshape(-1), k, **kw)
+            return vals, idx, resid.reshape(e_leaf.shape)
+        acc = e_leaf + u_leaf.astype(e_leaf.dtype)
+        return local_select(acc, k, compressor, key=key, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -218,6 +222,13 @@ def _comm_scope(tier: str, kind: str, label: str, nbytes: float, p: int):
         _obs_names.comm_name(tier, kind, label, nbytes=nbytes, p=p))
 
 
+def _phase_scope(phase: str, label: str = ""):
+    """``observe.trace.phase_scope``, imported lazily as in
+    :func:`_comm_scope`."""
+    from repro.observe.trace import phase_scope
+    return phase_scope(phase, label)
+
+
 def _sparse_mean_over(vals, idx, d: int, axes, *, tier: str = "flat",
                       label: str = "leaf") -> jax.Array:
     """All-gather each worker's sparse (vals, idx) over the manual
@@ -232,8 +243,9 @@ def _sparse_mean_over(vals, idx, d: int, axes, *, tier: str = "flat",
             vals_all = jax.lax.all_gather(vals, axes, tiled=False)
             idx_all = jax.lax.all_gather(idx, axes, tiled=False)
             return _gathered_scatter_mean(vals_all, idx_all, d,
-                                          _axis_prod(axes))
-    return C.decompress(vals, idx, d)
+                                          _axis_prod(axes), label=label)
+    with _phase_scope("scatter_mean", label):
+        return C.decompress(vals, idx, d)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,13 +281,16 @@ class DenseExchange:
         return treedef.unflatten(means), state
 
 
-def _gathered_scatter_mean(vals_all, idx_all, d: int, p) -> jax.Array:
-    """Sum every worker's sparse contribution into a dense vector, / P.
+def _gathered_scatter_mean(vals_all, idx_all, d: int, p, *,
+                           label: str = "") -> jax.Array:
+    """Sum every worker's sparse contribution into a dense vector, / P,
+    under the ``lags/scatter_mean/<label>`` phase scope.
 
     vals_all/idx_all: (P, k) or flattened (P*k,)."""
-    dense = jnp.zeros((d,), vals_all.dtype)
-    dense = dense.at[idx_all.reshape(-1)].add(vals_all.reshape(-1))
-    return dense / p
+    with _phase_scope("scatter_mean", label):
+        dense = jnp.zeros((d,), vals_all.dtype)
+        dense = dense.at[idx_all.reshape(-1)].add(vals_all.reshape(-1))
+        return dense / p
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,14 +333,16 @@ class LAGSExchange:
                     wkeys = _worker_keys(key, i, p)
                     vals, idx, resid = jax.vmap(
                         lambda uu, ee, kk: local_select_ef(
-                            uu, ee, k, self.compressor, key=kk, **kw)
+                            uu, ee, k, self.compressor, key=kk,
+                            label=f"l{i}", **kw)
                     )(u, e, wkeys)
                 else:
                     vals, idx, resid = jax.vmap(
                         lambda uu, ee: local_select_ef(
-                            uu, ee, k, self.compressor, **kw)
+                            uu, ee, k, self.compressor, label=f"l{i}", **kw)
                     )(u, e)
-                mean = _gathered_scatter_mean(vals, idx, d, p)
+                mean = _gathered_scatter_mean(vals, idx, d, p,
+                                              label=f"l{i}")
                 return mean.reshape(u.shape[1:]), resid
         else:
             # --- distributed path (inside shard_map manual axes) ----------
@@ -335,7 +352,8 @@ class LAGSExchange:
                 wk = (_leaf_key(key, i, _worker_index(axes)) if needs_key
                       else None)
                 vals, idx, resid = local_select_ef(u, e, k, self.compressor,
-                                                   key=wk, **kw)
+                                                   key=wk, label=f"l{i}",
+                                                   **kw)
                 # layer-wise sparse all-gather: ships 2*k scalars per worker
                 mean = _sparse_mean_over(vals, idx, u.size, axes,
                                          label=f"l{i}")
@@ -407,12 +425,13 @@ class SLGSExchange:
                 u_vec, e_vec = pack(us, es)
                 vals, idx, resid_vec = local_select_ef(
                     u_vec, e_vec, self.k_total, self.compressor,
-                    key=(wk if needs_key else None), **kw)
+                    key=(wk if needs_key else None), label="packed", **kw)
                 return vals, idx, resid_vec
 
             wkeys = _worker_keys(key, 0, p)
             vals, idx, resid_vec = jax.vmap(worker)(flat_u, flat_e, wkeys)
-            mean_vec = _gathered_scatter_mean(vals, idx, d, p)
+            mean_vec = _gathered_scatter_mean(vals, idx, d, p,
+                                              label="packed")
             means, resids, off = [], [], 0
             for u in flat_u:
                 n = int(u[0].size)
@@ -425,7 +444,8 @@ class SLGSExchange:
         u_vec, e_vec = pack(flat_u, flat_e)
         wk = _leaf_key(key, 0, _worker_index(axes)) if needs_key else None
         vals, idx, resid_vec = local_select_ef(u_vec, e_vec, self.k_total,
-                                               self.compressor, key=wk, **kw)
+                                               self.compressor, key=wk,
+                                               label="packed", **kw)
         mean_vec = _sparse_mean_over(vals, idx, u_vec.shape[0], axes,
                                      label="packed")
         means, resids, off = [], [], 0
@@ -574,7 +594,7 @@ class BlockLAGSExchange:
                 self.shard_dims)
         outs = [self._leaf(u, e, flat_k[i],
                            (flat_s[i] if flat_s is not None else None),
-                           axis_names)
+                           axis_names, f"l{i}")
                 for i, u, e in zip(ids, updates, state)]
         return [o[0] for o in outs], [o[1] for o in outs]
 
@@ -594,7 +614,9 @@ class BlockLAGSExchange:
             return None
         return sd + tuple(i for i in range(ndim) if i not in sd)
 
-    def _leaf(self, u, e, k, sdims, axis_names):
+    def _leaf(self, u, e, k, sdims, axis_names, label: str = ""):
+        """One leaf: select (``lags/select/<label>``), the sparse
+        all-gather, and the scatter-mean (``lags/scatter_mean/<label>``)."""
         param_shape = u.shape if axis_names is not None else u.shape[1:]
         size = 1
         for s in param_shape:
@@ -621,20 +643,26 @@ class BlockLAGSExchange:
                 return self._local_rows(to_flat(uu), to_flat(ee),
                                         n_blocks, bs, k_b)
 
-            vals, local, resid_rows = jax.vmap(worker)(u, e)
+            with _phase_scope("select", label):
+                vals, local, resid_rows = jax.vmap(worker)(u, e)
             # aggregate: (P, n_blocks, k_b) -> per-row scatter-add
-            idx_cat = jnp.moveaxis(local, 0, 1).reshape(n_blocks, p * k_b)
-            val_cat = jnp.moveaxis(vals, 0, 1).reshape(n_blocks, p * k_b)
-            mean_rows = self._pin_rows(jnp.zeros((n_blocks, bs), vals.dtype)) \
-                .at[row_ids, idx_cat].add(val_cat) / p
-            mean = from_flat(mean_rows.reshape(-1)[:size])
-            resid = jax.vmap(
-                lambda r: from_flat(r.reshape(-1)[:size]))(resid_rows)
+            with _phase_scope("scatter_mean", label):
+                idx_cat = jnp.moveaxis(local, 0, 1).reshape(n_blocks,
+                                                            p * k_b)
+                val_cat = jnp.moveaxis(vals, 0, 1).reshape(n_blocks, p * k_b)
+                mean_rows = self._pin_rows(
+                    jnp.zeros((n_blocks, bs), vals.dtype)) \
+                    .at[row_ids, idx_cat].add(val_cat) / p
+                mean = from_flat(mean_rows.reshape(-1)[:size])
+            with _phase_scope("select", label):
+                resid = jax.vmap(
+                    lambda r: from_flat(r.reshape(-1)[:size]))(resid_rows)
             return mean.astype(u.dtype), resid
 
         axes = tuple(axis_names)
-        vals, local, resid_rows = self._local_rows(
-            to_flat(u), to_flat(e), n_blocks, bs, k_b)
+        with _phase_scope("select", label):
+            vals, local, resid_rows = self._local_rows(
+                to_flat(u), to_flat(e), n_blocks, bs, k_b)
         if axes:
             # layer-wise sparse all-gather: 2*k_b scalars per block per worker
             with _comm_scope("flat", "allgather", "blocks",
@@ -648,10 +676,13 @@ class BlockLAGSExchange:
         else:
             p = 1
             idx_cat, val_cat = local, vals
-        mean_rows = self._pin_rows(jnp.zeros((n_blocks, bs), vals.dtype)) \
-            .at[row_ids, idx_cat].add(val_cat) / p
-        mean = from_flat(mean_rows.reshape(-1)[:size])
-        resid = from_flat(resid_rows.reshape(-1)[:size])
+        with _phase_scope("scatter_mean", label):
+            mean_rows = self._pin_rows(
+                jnp.zeros((n_blocks, bs), vals.dtype)) \
+                .at[row_ids, idx_cat].add(val_cat) / p
+            mean = from_flat(mean_rows.reshape(-1)[:size])
+        with _phase_scope("select", label):
+            resid = from_flat(resid_rows.reshape(-1)[:size])
         return mean.astype(u.dtype), resid
 
 
@@ -701,7 +732,7 @@ class HierLAGSExchange:
             wk = (_leaf_key(key, i, _worker_index(self.outer_axes))
                   if needs_key else None)
             vals, idx, resid = local_select_ef(u, e, k, self.compressor,
-                                               key=wk, **kw)
+                                               key=wk, label=f"l{i}", **kw)
             mean = _sparse_mean_over(vals, idx, u.size, self.outer_axes,
                                      tier="outer", label=f"l{i}")
             return mean.reshape(u.shape).astype(u.dtype), resid
@@ -833,16 +864,18 @@ class SparseHierLAGSExchange:
                     wkeys = _worker_keys(key, i, p)
                     vals, idx, resid_in = jax.vmap(
                         lambda uu, ee, kk: local_select_ef(
-                            uu, ee, k_in, icomp, key=kk, **ikw)
+                            uu, ee, k_in, icomp, key=kk, label=f"l{i}",
+                            **ikw)
                     )(u, e_in, wkeys)
                 else:
                     vals, idx, resid_in = jax.vmap(
                         lambda uu, ee: local_select_ef(
-                            uu, ee, k_in, icomp, **ikw)
+                            uu, ee, k_in, icomp, label=f"l{i}", **ikw)
                     )(u, e_in)
                 # intra-pod scatter-mean: group the (P, k) selections by pod
                 m = jax.vmap(
-                    lambda v, ix: _gathered_scatter_mean(v, ix, d, n_in))(
+                    lambda v, ix: _gathered_scatter_mean(
+                        v, ix, d, n_in, label=f"l{i}"))(
                         vals.reshape(n_out, n_in, -1),
                         idx.reshape(n_out, n_in, -1))       # (n_out, d)
                 # outer tier: one accumulator per pod (e_out is replicated
@@ -863,14 +896,15 @@ class SparseHierLAGSExchange:
                         jnp.arange(o_base, o_base + n_out))
                     vals2, idx2, resid_out = jax.vmap(
                         lambda mm, ee, kk: local_select_ef(
-                            mm, ee, k_out, comp, key=kk, **kw)
+                            mm, ee, k_out, comp, key=kk, label=f"l{i}", **kw)
                     )(m_pod, e_pod, okeys)
                 else:
                     vals2, idx2, resid_out = jax.vmap(
-                        lambda mm, ee: local_select_ef(mm, ee, k_out, comp,
-                                                       **kw)
+                        lambda mm, ee: local_select_ef(
+                            mm, ee, k_out, comp, label=f"l{i}", **kw)
                     )(m_pod, e_pod)
-                mean = _gathered_scatter_mean(vals2, idx2, d, n_out)
+                mean = _gathered_scatter_mean(vals2, idx2, d, n_out,
+                                              label=f"l{i}")
                 resid_out_full = jnp.broadcast_to(
                     resid_out[:, None],
                     (n_out, n_in) + resid_out.shape[1:]).reshape(e_out.shape)
@@ -892,7 +926,8 @@ class SparseHierLAGSExchange:
                 wk_in = (_leaf_key(key, i, _worker_index(axes))
                          if needs_key_in else None)
                 vals, idx, resid_in = local_select_ef(u, e_in, k_in, icomp,
-                                                      key=wk_in, **ikw)
+                                                      key=wk_in,
+                                                      label=f"l{i}", **ikw)
                 m = _sparse_mean_over(vals, idx, u.size, inner,
                                       tier="inner", label=f"l{i}")
                 # outer accumulator is pod-replicated: outer-only key so
@@ -903,7 +938,8 @@ class SparseHierLAGSExchange:
                 wk_out = (_leaf_key(key, i, o_base + _worker_index(outer))
                           if needs_key else None)
                 vals2, idx2, resid_out = local_select_ef(
-                    m.reshape(u.shape), e_out, k_out, comp, key=wk_out, **kw)
+                    m.reshape(u.shape), e_out, k_out, comp, key=wk_out,
+                    label=f"l{i}", **kw)
                 mean = _sparse_mean_over(vals2, idx2, u.size, outer,
                                          tier="outer", label=f"l{i}")
                 return (mean.reshape(u.shape).astype(u.dtype),

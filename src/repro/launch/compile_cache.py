@@ -19,8 +19,13 @@ def place() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
     other directory is set here; otherwise the cache goes to
     ``<repo>/.jax_cache``.
+
+    Either way the cache key includes the program's metadata: without it
+    a step whose ops gained or lost a ``lags/<phase>`` scope loads the
+    executable compiled before, and a profile shows that one's op names.
     """
     import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

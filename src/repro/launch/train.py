@@ -67,6 +67,7 @@ from repro.core import lags
 from repro.launch import mesh as M
 from repro.models import transformer as T
 from repro.observe import health as OH
+from repro.observe.trace import phase_scope
 from repro.pipeline import buckets as WB
 from repro.pipeline import step as WS
 from repro.pipeline import waves as WW
@@ -339,113 +340,124 @@ def build_train_step(cfg, mesh, run: RunConfig):
 
     def worker(params, ef, pending, extra, batch, step_no):
         # per-worker state (ef / pending / extra) arrives (1, ...) under
-        # the manual axes
-        ef_local = jax.tree.map(lambda e: e[0], ef) if mode != "dense" else ()
+        # the manual axes; the residual's unpacking and repacking are the
+        # exchange's (a full pass over the f32 residual each)
+        with phase_scope("exchange"):
+            ef_local = (jax.tree.map(lambda e: e[0], ef) if mode != "dense"
+                        else ())
         lr_f = lr_at(step_no)
         axis_names = manual if manual else ()
 
         if pipeline == "wave":
             # in-backprop waved exchange: each wave's select+pack+
             # collective fires via a custom_vjp tap the moment backprop
-            # produces that wave's cotangents (bitwise equal to "off")
-            (loss, _aux), mean_upd, new_ef_local = WS.wave_backward(
-                lambda p: loss_fn(p, batch), exch, waves_sched.waves,
-                params, ef_local, axis_names, lr=lr_f,
-                key=step_key(step_no), has_aux=True, tiers=ef_tiers)
+            # produces that wave's cotangents (bitwise equal to "off");
+            # the taps open their own lags/exchange scope
+            with phase_scope("fwd"):
+                (loss, _aux), mean_upd, new_ef_local = WS.wave_backward(
+                    lambda p: loss_fn(p, batch), exch, waves_sched.waves,
+                    params, ef_local, axis_names, lr=lr_f,
+                    key=step_key(step_no), has_aux=True, tiers=ef_tiers)
             new_pending, new_extra = pending, extra
         else:
-            (loss, _aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch)
-            if mc > 0.0:
-                # DGC momentum correction: the velocity accumulates
-                # BEFORE sparsification, per worker
-                mom = jax.tree.map(lambda m: m[0], extra["mom"])
-                new_mom = jax.tree.map(
-                    lambda m, g: mc * m + lr_f * g.astype(jnp.float32),
-                    mom, grads)
-                updates = new_mom
-                new_extra = {"mom": jax.tree.map(lambda m: m[None], new_mom)}
-            else:
-                updates = jax.tree.map(
-                    lambda g: lr_f * g.astype(jnp.float32), grads)
-                new_extra = extra
-            if pipeline == "async1":
-                # double-buffer: exchange the PREVIOUS step's updates
-                # (zeros at step 0, hence that step's key) while this
-                # step's compute runs; the fresh updates become the next
-                # step's pending payload — one step of bounded staleness
-                pend = jax.tree.map(lambda x: x[0], pending)
-                mean_upd, new_ef_local = WS.waved_exchange(
-                    exch, waves_sched.waves, pend, ef_local, axis_names,
-                    key=step_key(step_no - 1), tiers=ef_tiers)
-                new_pending = jax.tree.map(lambda u: u[None], updates)
-            else:
-                new_pending = pending
-                if mode == "dense":
-                    if manual:
-                        mean_upd, _ = exch.exchange(updates, (), manual)
-                    else:
-                        mean_upd = updates
-                    new_ef_local = ()
+            with phase_scope("fwd"):
+                (loss, _aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch)
+            with phase_scope("exchange"):
+                if mc > 0.0:
+                    # DGC momentum correction: the velocity accumulates
+                    # BEFORE sparsification, per worker
+                    mom = jax.tree.map(lambda m: m[0], extra["mom"])
+                    new_mom = jax.tree.map(
+                        lambda m, g: mc * m + lr_f * g.astype(jnp.float32),
+                        mom, grads)
+                    updates = new_mom
+                    new_extra = {"mom": jax.tree.map(lambda m: m[None],
+                                                     new_mom)}
                 else:
-                    mean_upd, new_ef_local = exch.exchange(
-                        updates, ef_local, axis_names,
-                        key=step_key(step_no))
-        new_ef = (jax.tree.map(lambda e: e[None], new_ef_local)
-                  if mode != "dense" else ())
-        new_params = jax.tree.map(
-            lambda p, d: (p.astype(jnp.float32) - d).astype(p.dtype),
-            params, mean_upd)
-        if manual:
-            loss = lags._psum_mean(loss, manual)
+                    updates = jax.tree.map(
+                        lambda g: lr_f * g.astype(jnp.float32), grads)
+                    new_extra = extra
+                if pipeline == "async1":
+                    # double-buffer: exchange the PREVIOUS step's updates
+                    # (zeros at step 0, hence that step's key) while this
+                    # step's compute runs; the fresh updates become the next
+                    # step's pending payload — one step of bounded staleness
+                    pend = jax.tree.map(lambda x: x[0], pending)
+                    mean_upd, new_ef_local = WS.waved_exchange(
+                        exch, waves_sched.waves, pend, ef_local, axis_names,
+                        key=step_key(step_no - 1), tiers=ef_tiers)
+                    new_pending = jax.tree.map(lambda u: u[None], updates)
+                else:
+                    new_pending = pending
+                    if mode == "dense":
+                        if manual:
+                            mean_upd, _ = exch.exchange(updates, (), manual)
+                        else:
+                            mean_upd = updates
+                        new_ef_local = ()
+                    else:
+                        mean_upd, new_ef_local = exch.exchange(
+                            updates, ef_local, axis_names,
+                            key=step_key(step_no))
+        with phase_scope("exchange"):
+            new_ef = (jax.tree.map(lambda e: e[None], new_ef_local)
+                      if mode != "dense" else ())
+        with phase_scope("apply"):
+            new_params = jax.tree.map(
+                lambda p, d: (p.astype(jnp.float32) - d).astype(p.dtype),
+                params, mean_upd)
+            if manual:
+                loss = lags._psum_mean(loss, manual)
         metrics = {"loss": loss}
         if health:
-            if ef_tiers:
-                # two-tier: delta gates the slow cross-pod (outer) wire.
-                # The outer residual is pod-replicated, so the psum over
-                # the pod axis alone is exactly sum-over-pods.
-                e_sum = (jax.lax.psum(new_ef_local["outer"], outer_axes_h)
-                         if outer_axes_h else new_ef_local["outer"])
-                delta = OH.delta_leaves_from_mean(
-                    e_sum, mean_upd, exch.ks, n_out_h)
-                agg = jax.tree.map(lambda e, m: e + n_out_h * m,
-                                   e_sum, mean_upd)
-                metrics["health_ef_energy_outer"] = OH.safe_ratio(
-                    OH.sq_leaves(e_sum), OH.sq_leaves(agg))
-                if pipeline != "wave":
-                    src = pend if pipeline == "async1" else updates
-                    acc_in = jax.tree.map(lambda e, u: e + u,
-                                          ef_local["inner"], src)
-                    metrics["health_ef_energy_inner"] = OH.safe_ratio(
-                        jax.lax.psum(OH.sq_leaves(new_ef_local["inner"]),
-                                     manual),
-                        jax.lax.psum(OH.sq_leaves(acc_in), manual))
-            else:
-                e_sum = jax.lax.psum(new_ef_local, manual)
-                delta = OH.delta_leaves_from_mean(
-                    e_sum, mean_upd, exch.ks, n_w_h)
-                if pipeline == "wave":
-                    # the wave taps consume the updates inside backprop:
-                    # fall back to the aggregate energy form
-                    agg = jax.tree.map(lambda e, m: e + n_w_h * m,
+            with phase_scope("health"):
+                if ef_tiers:
+                    # two-tier: delta gates the slow cross-pod (outer) wire.
+                    # The outer residual is pod-replicated, so the psum over
+                    # the pod axis alone is exactly sum-over-pods.
+                    e_sum = (jax.lax.psum(new_ef_local["outer"], outer_axes_h)
+                             if outer_axes_h else new_ef_local["outer"])
+                    delta = OH.delta_leaves_from_mean(
+                        e_sum, mean_upd, exch.ks, n_out_h)
+                    agg = jax.tree.map(lambda e, m: e + n_out_h * m,
                                        e_sum, mean_upd)
-                    metrics["health_ef_energy_flat"] = OH.safe_ratio(
+                    metrics["health_ef_energy_outer"] = OH.safe_ratio(
                         OH.sq_leaves(e_sum), OH.sq_leaves(agg))
+                    if pipeline != "wave":
+                        src = pend if pipeline == "async1" else updates
+                        acc_in = jax.tree.map(lambda e, u: e + u,
+                                              ef_local["inner"], src)
+                        metrics["health_ef_energy_inner"] = OH.safe_ratio(
+                            jax.lax.psum(OH.sq_leaves(new_ef_local["inner"]),
+                                         manual),
+                            jax.lax.psum(OH.sq_leaves(acc_in), manual))
                 else:
-                    src = pend if pipeline == "async1" else updates
-                    acc = jax.tree.map(lambda e, u: e + u, ef_local, src)
-                    metrics["health_ef_energy_flat"] = OH.safe_ratio(
-                        jax.lax.psum(OH.sq_leaves(new_ef_local), manual),
-                        jax.lax.psum(OH.sq_leaves(acc), manual))
-            metrics["health_delta"] = delta
-            metrics["health_delta_max"] = delta.max()
-            if pipeline == "async1":
-                u_sq = sum(OH.sq_norm(x) for x in jax.tree.leaves(updates))
-                d_sq = sum(OH.sq_norm(u - q)
-                           for u, q in zip(jax.tree.leaves(updates),
-                                           jax.tree.leaves(pend)))
-                metrics["health_staleness"] = OH.staleness_gap(
-                    jax.lax.psum(u_sq, manual), jax.lax.psum(d_sq, manual))
+                    e_sum = jax.lax.psum(new_ef_local, manual)
+                    delta = OH.delta_leaves_from_mean(
+                        e_sum, mean_upd, exch.ks, n_w_h)
+                    if pipeline == "wave":
+                        # the wave taps consume the updates inside backprop:
+                        # fall back to the aggregate energy form
+                        agg = jax.tree.map(lambda e, m: e + n_w_h * m,
+                                           e_sum, mean_upd)
+                        metrics["health_ef_energy_flat"] = OH.safe_ratio(
+                            OH.sq_leaves(e_sum), OH.sq_leaves(agg))
+                    else:
+                        src = pend if pipeline == "async1" else updates
+                        acc = jax.tree.map(lambda e, u: e + u, ef_local, src)
+                        metrics["health_ef_energy_flat"] = OH.safe_ratio(
+                            jax.lax.psum(OH.sq_leaves(new_ef_local), manual),
+                            jax.lax.psum(OH.sq_leaves(acc), manual))
+                metrics["health_delta"] = delta
+                metrics["health_delta_max"] = delta.max()
+                if pipeline == "async1":
+                    u_sq = sum(OH.sq_norm(x) for x in jax.tree.leaves(updates))
+                    d_sq = sum(OH.sq_norm(u - q)
+                               for u, q in zip(jax.tree.leaves(updates),
+                                               jax.tree.leaves(pend)))
+                    metrics["health_staleness"] = OH.staleness_gap(
+                        jax.lax.psum(u_sq, manual), jax.lax.psum(d_sq, manual))
         return new_params, new_ef, new_pending, new_extra, metrics
 
     if manual:
@@ -512,74 +524,80 @@ def build_train_step(cfg, mesh, run: RunConfig):
 
         def step(state, batch):
             params, ef = state["params"], state["ef"]
-            if n_w > 1:
-                lead = worker_axes if len(worker_axes) > 1 else worker_axes[0]
+            with phase_scope("fwd"):
+                if n_w > 1:
+                    lead = (worker_axes if len(worker_axes) > 1
+                            else worker_axes[0])
 
-                def resh(x):
-                    y = x.reshape((n_w, x.shape[0] // n_w) + x.shape[1:])
-                    return jax.lax.with_sharding_constraint(
-                        y, P(lead, "data", *([None] * (len(x.shape) - 1))))
-                vb = jax.tree.map(resh, batch)
-                (losses, _aux), grads = jax.vmap(
-                    lambda b: jax.value_and_grad(loss_fn, has_aux=True)(
-                        params, b))(vb)
-                loss = losses.mean()
-            else:
-                (loss, _aux), g1 = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, batch)
-                grads = jax.tree.map(lambda g: g[None], g1)
-            lr_f = lr_at(state["step"])
-            if mc > 0.0:
-                # DGC velocity, leading-P layout (no manual slicing here)
-                new_mom = jax.tree.map(
-                    lambda m, g: mc * m + lr_f * g.astype(jnp.float32),
-                    state["extra"]["mom"], grads)
-                updates = new_mom
-            else:
-                updates = jax.tree.map(
-                    lambda g: lr_f * g.astype(jnp.float32), grads)
-            # async1 exchanges the PREVIOUS step's updates (that step's
-            # key); "wave" on this pure-auto path is post-backward
-            # regrouping only — taps cannot reach inside the per-pod vmap,
-            # so it buys semantics parity, not overlap (use lags_dp /
-            # lags_hier2 for in-backprop waves)
-            src = state["pending"] if pipeline == "async1" else updates
-            if mode == "dense":
-                mean_upd = jax.tree.map(lambda u: u.mean(0), src)
-                new_ef = ()
-            elif pipeline == "off":
-                mean_upd, new_ef = exch.exchange(updates, ef, None,
-                                                 key=step_key(state["step"]))
-            else:
-                key = (step_key(state["step"] - 1) if pipeline == "async1"
-                       else step_key(state["step"]))
-                mean_upd, new_ef = WS.waved_exchange(
-                    exch, waves_sched.waves, src, ef, None, key=key,
-                    tiers=ef_tiers)
-            new_params = jax.tree.map(
-                lambda p, d: (p.astype(jnp.float32) - d).astype(p.dtype),
-                params, mean_upd)
+                    def resh(x):
+                        y = x.reshape((n_w, x.shape[0] // n_w) + x.shape[1:])
+                        return jax.lax.with_sharding_constraint(
+                            y, P(lead, "data",
+                                 *([None] * (len(x.shape) - 1))))
+                    vb = jax.tree.map(resh, batch)
+                    (losses, _aux), grads = jax.vmap(
+                        lambda b: jax.value_and_grad(loss_fn, has_aux=True)(
+                            params, b))(vb)
+                    loss = losses.mean()
+                else:
+                    (loss, _aux), g1 = jax.value_and_grad(
+                        loss_fn, has_aux=True)(params, batch)
+                    grads = jax.tree.map(lambda g: g[None], g1)
+            with phase_scope("exchange"):
+                lr_f = lr_at(state["step"])
+                if mc > 0.0:
+                    # DGC velocity, leading-P layout (no manual slicing here)
+                    new_mom = jax.tree.map(
+                        lambda m, g: mc * m + lr_f * g.astype(jnp.float32),
+                        state["extra"]["mom"], grads)
+                    updates = new_mom
+                else:
+                    updates = jax.tree.map(
+                        lambda g: lr_f * g.astype(jnp.float32), grads)
+                # async1 exchanges the PREVIOUS step's updates (that step's
+                # key); "wave" on this pure-auto path is post-backward
+                # regrouping only — taps cannot reach inside the per-pod
+                # vmap, so it buys semantics parity, not overlap (use
+                # lags_dp / lags_hier2 for in-backprop waves)
+                src = state["pending"] if pipeline == "async1" else updates
+                if mode == "dense":
+                    mean_upd = jax.tree.map(lambda u: u.mean(0), src)
+                    new_ef = ()
+                elif pipeline == "off":
+                    mean_upd, new_ef = exch.exchange(
+                        updates, ef, None, key=step_key(state["step"]))
+                else:
+                    key = (step_key(state["step"] - 1) if pipeline == "async1"
+                           else step_key(state["step"]))
+                    mean_upd, new_ef = WS.waved_exchange(
+                        exch, waves_sched.waves, src, ef, None, key=key,
+                        tiers=ef_tiers)
+            with phase_scope("apply"):
+                new_params = jax.tree.map(
+                    lambda p, d: (p.astype(jnp.float32) - d).astype(p.dtype),
+                    params, mean_upd)
             metrics = {"loss": loss}
             if health and not ef_tiers:
-                # leading-P layout under GSPMD: same form as the sim
-                # surface (the lags_hier factory builds the flat leading-P
-                # exchange; dict EF never reaches this path)
-                e_sum = jax.tree.map(lambda e: e.sum(0), new_ef)
-                delta = OH.delta_leaves_from_mean(
-                    e_sum, mean_upd, exch.ks, n_w)
-                acc = jax.tree.map(lambda e, u: e + u, ef, src)
-                metrics["health_ef_energy_flat"] = OH.energy_leaves(
-                    new_ef, acc)
-                metrics["health_delta"] = delta
-                metrics["health_delta_max"] = delta.max()
-                if pipeline == "async1":
-                    u_sq = sum(OH.sq_norm(x)
-                               for x in jax.tree.leaves(updates))
-                    d_sq = sum(OH.sq_norm(u - q)
-                               for u, q in zip(jax.tree.leaves(updates),
-                                               jax.tree.leaves(src)))
-                    metrics["health_staleness"] = OH.staleness_gap(
-                        u_sq, d_sq)
+                with phase_scope("health"):
+                    # leading-P layout under GSPMD: same form as the sim
+                    # surface (the lags_hier factory builds the flat leading-P
+                    # exchange; dict EF never reaches this path)
+                    e_sum = jax.tree.map(lambda e: e.sum(0), new_ef)
+                    delta = OH.delta_leaves_from_mean(
+                        e_sum, mean_upd, exch.ks, n_w)
+                    acc = jax.tree.map(lambda e, u: e + u, ef, src)
+                    metrics["health_ef_energy_flat"] = OH.energy_leaves(
+                        new_ef, acc)
+                    metrics["health_delta"] = delta
+                    metrics["health_delta_max"] = delta.max()
+                    if pipeline == "async1":
+                        u_sq = sum(OH.sq_norm(x)
+                                   for x in jax.tree.leaves(updates))
+                        d_sq = sum(OH.sq_norm(u - q)
+                                   for u, q in zip(jax.tree.leaves(updates),
+                                                   jax.tree.leaves(src)))
+                        metrics["health_staleness"] = OH.staleness_gap(
+                            u_sq, d_sq)
             out = {"params": new_params, "ef": new_ef,
                    "step": state["step"] + 1}
             if pipeline == "async1":

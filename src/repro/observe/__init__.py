@@ -8,12 +8,11 @@ micro-benchmark probe, and ``ReplanController`` re-planned on a blind
 fixed cadence.  ``repro.observe`` turns that controller from
 cadence-driven into evidence-driven, in four pieces:
 
-  * :mod:`~repro.observe.trace` — capture around instrumented steps:
-    annotation primitives (``jax.named_scope`` names on the
-    ``core.lags`` collectives follow the :mod:`~repro.observe.names`
-    grammar), a real ``jax.profiler`` capture wrapper, and a
-    **deterministic fake-trace backend** for CPU/CI where device traces
-    are unavailable/unparseable.
+  * :mod:`~repro.observe.trace` — annotation primitives (the train
+    step's phase scopes, the ``core.lags`` collectives and the
+    ``Session.run`` host spans follow the :mod:`~repro.observe.names`
+    grammar, so a ``jax.profiler`` trace carries it) and a
+    **deterministic fake-trace backend** for CPU/CI.
   * :mod:`~repro.observe.attribution` — trace events → per-bucket
     ``CommSample``\\ s (consumed by ``costfit``/``tier_hardware``) and
     **measured** per-leaf backward times (consumed by
@@ -66,8 +65,6 @@ _LAZY = {
     "Trace": ("repro.observe.trace", "Trace"),
     "TraceEvent": ("repro.observe.trace", "TraceEvent"),
     "FakeTraceBackend": ("repro.observe.trace", "FakeTraceBackend"),
-    "capture_jax_trace": ("repro.observe.trace", "capture_jax_trace"),
-    "export_chrome_trace": ("repro.observe.trace", "export_chrome_trace"),
     "AnomalyConfig": ("repro.observe.anomaly", "AnomalyConfig"),
     "StepTimeAnomalyDetector": ("repro.observe.anomaly",
                                 "StepTimeAnomalyDetector"),

@@ -1,38 +1,28 @@
-"""Trace capture around instrumented train steps.
-
-Two backends produce the same artifact — a :class:`Trace` of named
-:class:`TraceEvent`\\ s using the ``repro.observe.names`` grammar — so
-everything downstream (:mod:`repro.observe.attribution`, the runtime
-controller, benchmarks) is backend-agnostic:
-
-  * :class:`FakeTraceBackend` — **deterministic** synthesis from the α–β
-    cost model: per-leaf backward events from measured budgets, per-leaf
-    collective events priced on the live wire, and a step event from the
-    pipelined LAGS timeline (``cm.iteration_time_lags``).  This is the
-    CPU/CI backend: host platforms produce no parseable device traces,
-    and benchmarks need an *injectable* wire anyway.
-  * :func:`capture_jax_trace` — real ``jax.profiler`` capture around N
-    calls of a step function.  The collectives in ``core.lags`` run
-    under ``jax.named_scope`` annotations carrying the same names, so a
-    real device trace groups ops per bucket/collective; jax writes
-    XPlane protos that need the TensorBoard profile plugin to decode, so
-    on this container the capture returns an *empty* Trace whose
-    ``meta["trace_dir"]`` points at the raw artifact (see README
-    caveat).  Any ``trace.json``/``trace.json.gz`` the tooling did emit
-    is parsed best-effort into events.
+"""Trace instrumentation and the deterministic fake-trace backend.
 
 ``annotation(name)`` (host-side ``TraceAnnotation``) and
 ``device_annotation(name)`` (``jax.named_scope``, usable inside jit)
-are the two instrumentation primitives.
+are the two instrumentation primitives.  Real captures need no code
+here: a ``jax.profiler`` trace of ``api.Session.run`` carries the
+``repro.observe.names`` grammar in the compiled ops' ``op_name``
+(``lags/fwd``, ``lags/exchange``, ``lags/select/l<i>``, ...) and in the
+host plane (``lags/step``, ``lags/host/...``), and
+``benchmarks/chip/lagsbench/xplane.py`` reduces the ``.xplane.pb`` with
+``jax.profiler.ProfileData``.
+
+:class:`FakeTraceBackend` synthesizes a :class:`Trace` of named
+:class:`TraceEvent`\\ s from the α–β cost model: per-leaf backward
+events from measured budgets, per-leaf collective events priced on the
+live wire, and a step event from the pipelined LAGS timeline
+(``cm.iteration_time_lags``).  It is **deterministic**, so CPU/CI runs
+of :mod:`repro.observe.attribution`, the runtime controller and the
+benchmarks see an injectable wire with no wall-clock noise.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import glob
-import gzip
 import json
-import os
 from typing import Any, Callable, Sequence
 
 from repro.core import comm_model as cm
@@ -69,35 +59,6 @@ class Trace:
                      meta=dict(obj.get("meta", {})))
 
 
-def export_chrome_trace(trace: Trace, path: str) -> str:
-    """Write ``trace`` as Perfetto-loadable chrome-trace-format JSON.
-
-    The inverse of :func:`_parse_chrome_trace`: every event becomes a
-    complete ``"ph": "X"`` slice with ``ts``/``dur`` in microseconds, so
-    a FakeTraceBackend synthesis (wave/overlap events included) opens in
-    ``ui.perfetto.dev`` / ``chrome://tracing`` and round-trips through
-    ``_events_from_chrome_obj`` unchanged.  Trace meta rides in
-    ``otherData``; ``.gz`` paths are gzip-compressed.  Returns ``path``.
-    """
-    obj = {
-        "traceEvents": [
-            {"name": e.name, "ph": "X", "pid": 0, "tid": 0,
-             "ts": e.t_start * 1e6, "dur": e.dur * 1e6,
-             "cat": (names.parse(e.name) or {}).get("type", "span")}
-            for e in trace.events
-        ],
-        "displayTimeUnit": "ms",
-        "otherData": dict(trace.meta),
-    }
-    parent = os.path.dirname(path)
-    if parent:
-        os.makedirs(parent, exist_ok=True)
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "wt") as f:
-        json.dump(obj, f)
-    return path
-
-
 def annotation(name: str):
     """Host-side profiler annotation (no-op when jax lacks the API)."""
     import jax
@@ -112,102 +73,11 @@ def device_annotation(name: str):
     return jax.named_scope(name)
 
 
-# ---------------------------------------------------------------------------
-# real backend: jax.profiler capture
-# ---------------------------------------------------------------------------
-
-def _events_from_chrome_obj(obj: dict) -> list[TraceEvent]:
-    """Chrome-trace-format dict -> grammar-named events (``ts``/``dur``
-    in µs)."""
-    out = []
-    for ev in obj.get("traceEvents", []):
-        name = ev.get("name", "")
-        if names.parse(name) is None or ev.get("ph") not in (None, "X"):
-            continue
-        out.append(TraceEvent(name=name,
-                              t_start=float(ev.get("ts", 0.0)) * 1e-6,
-                              dur=float(ev.get("dur", 0.0)) * 1e-6))
-    return out
-
-
-def _parse_chrome_trace(path: str) -> list[TraceEvent]:
-    """Best-effort chrome-trace-format parse."""
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as f:
-        obj = json.load(f)
-    return _events_from_chrome_obj(obj)
-
-
-def _xplane_converter():
-    """The TensorBoard profile plugin's XPlane -> trace-viewer converter,
-    or None when the optional dependency is absent (this container)."""
-    try:
-        from tensorboard_plugin_profile.convert import raw_to_tool_data
-        return raw_to_tool_data.xspace_to_tool_data
-    except Exception:
-        return None
-
-
-def decode_xplane(log_dir: str) -> list[TraceEvent]:
-    """Best-effort XPlane proto decode via the TensorBoard profile
-    plugin: every ``*.xplane.pb`` under ``log_dir`` is converted to
-    trace-viewer (chrome) JSON and parsed through the same grammar
-    filter as a native chrome trace.  Returns ``[]`` when the plugin is
-    not installed or a proto fails to convert — callers fall back to the
-    chrome-format parse / empty-Trace path."""
-    convert = _xplane_converter()
-    if convert is None:
-        return []
-    out: list[TraceEvent] = []
-    for path in sorted(glob.glob(os.path.join(log_dir, "**/*.xplane.pb"),
-                                 recursive=True)):
-        try:
-            data = convert([path], "trace_viewer", {})
-            if isinstance(data, tuple):   # newer plugin: (data, mimetype)
-                data = data[0]
-            out.extend(_events_from_chrome_obj(json.loads(data)))
-        except Exception:
-            continue
-    return out
-
-
-def capture_jax_trace(step_fn: Callable, *args, log_dir: str,
-                      steps: int = 1) -> Trace:
-    """Run ``step_fn(*args)`` ``steps`` times under ``jax.profiler.trace``.
-
-    Decoding is best-effort, in order of fidelity: a chrome-format trace
-    the runtime emitted directly, then the XPlane protos through the
-    TensorBoard profile plugin when that optional import is available
-    (:func:`decode_xplane`).  ``meta["decoder"]`` records which decoder
-    produced the events (``"chrome"`` | ``"xplane"`` | ``"none"``); with
-    no decoder the Trace is empty and ``meta["trace_dir"]`` points at
-    the raw artifacts for offline decoding.
-    """
-    import jax
-    os.makedirs(log_dir, exist_ok=True)
-    with jax.profiler.trace(log_dir):
-        out = None
-        for i in range(steps):
-            with annotation(names.STEP):
-                out = step_fn(*args)
-        jax.block_until_ready(out)
-    events: list[TraceEvent] = []
-    decoder = "none"
-    for pattern in ("**/*.trace.json.gz", "**/*.trace.json",
-                    "**/trace.json.gz", "**/trace.json"):
-        for path in glob.glob(os.path.join(log_dir, pattern),
-                              recursive=True):
-            events.extend(_parse_chrome_trace(path))
-    if events:
-        decoder = "chrome"
-    else:
-        events = decode_xplane(log_dir)
-        if events:
-            decoder = "xplane"
-    return Trace(events=tuple(events),
-                 meta={"backend": "jax.profiler", "trace_dir": log_dir,
-                       "steps": int(steps), "parsed": bool(events),
-                       "decoder": decoder})
+def phase_scope(phase: str, label: str = ""):
+    """In-jit ``lags/<phase>[/<label>]`` scope over one phase of the
+    train step: every op traced under it carries the phase in its
+    ``op_name``, where ``names.phase_of`` reads it back."""
+    return device_annotation(names.phase_name(phase, label))
 
 
 # ---------------------------------------------------------------------------

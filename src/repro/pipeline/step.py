@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.observe.trace import phase_scope
+
 
 # -- flat-state plumbing (handles the three EF layouts uniformly) -----------
 
@@ -114,10 +116,12 @@ def _make_tap(exch, wave, axis_names):
 
     def tap_bwd(res, g):
         efs, lr, key = res
-        # EXACTLY the monolithic worker's update law: lr * grad in fp32
-        updates = [lr * gi.astype(jnp.float32) for gi in g]
-        means, new_efs = exch.exchange_bucket(ids, updates, efs, axis_names,
-                                              key=key)
+        # EXACTLY the monolithic worker's update law: lr * grad in fp32;
+        # under lags/exchange, so a profile counts it as exchange, not bwd
+        with phase_scope("exchange"):
+            updates = [lr * gi.astype(jnp.float32) for gi in g]
+            means, new_efs = exch.exchange_bucket(ids, updates, efs,
+                                                  axis_names, key=key)
         key_ct = np.zeros(key.shape, jax.dtypes.float0)
         return (list(g), _zeros_like_state(efs), (new_efs, means),
                 jnp.zeros_like(lr), key_ct)
@@ -177,9 +181,10 @@ def waved_exchange(exch, waves: Sequence, updates, state, axis_names, *,
     new_flat_state = _empty_like(flat_state)
     for w in waves:
         ids = tuple(int(i) for i in w.leaf_ids)
-        means, new_sub = exch.exchange_bucket(
-            ids, [flat_u[i] for i in ids], _slice_state(flat_state, ids),
-            axis_names, key=key)
+        with phase_scope("exchange"):
+            means, new_sub = exch.exchange_bucket(
+                ids, [flat_u[i] for i in ids], _slice_state(flat_state, ids),
+                axis_names, key=key)
         for j, i in enumerate(ids):
             flat_means[i] = means[j]
         _scatter_state(new_flat_state, new_sub, ids)

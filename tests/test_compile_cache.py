@@ -10,8 +10,11 @@ from repro.launch import compile_cache
 def cache_dir_config():
     """Restore the process-wide cache directory after the test."""
     was = jax.config.jax_compilation_cache_dir
+    was_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      was_meta)
     cc.reset_cache()
 
 
@@ -20,6 +23,20 @@ def test_env_var_wins_and_nothing_else_is_set(monkeypatch, cache_dir_config):
     before = jax.config.jax_compilation_cache_dir
     assert compile_cache.place() == "/some/shared/cache"
     assert jax.config.jax_compilation_cache_dir == before
+
+
+@pytest.mark.parametrize("env", ["/some/shared/cache", None])
+def test_cache_key_includes_metadata(monkeypatch, cache_dir_config, env):
+    """A step that differs only in its op names (the phase scopes) must
+    not load the other one's executable: its profile would show stale
+    names."""
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    compile_cache.place()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_unset_env_var_places_cache_in_repo(monkeypatch, cache_dir_config):

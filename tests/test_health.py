@@ -78,7 +78,6 @@ class TestHealthNames:
         import repro.observe as O
         assert O.HealthMonitor is H.HealthMonitor
         assert O.HealthTrigger is TG.HealthTrigger
-        assert callable(O.export_chrome_trace)
         assert O.health is H
 
 

@@ -4,9 +4,6 @@ edge cases, replan triggers, and the controller's trace-driven
 measurement path (incl. detector state through checkpoint.io)."""
 import dataclasses
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.autotune import costfit, profiler
@@ -75,6 +72,58 @@ class TestNames:
         assert names.parse("serve/oops") is None
         assert names.parse("serve/apply/x?version=bad")["version"] is None
 
+    def test_phase_and_host_names_roundtrip(self):
+        assert names.parse(names.phase_name("select", "l3")) == \
+            {"type": "phase", "phase": "select", "label": "l3"}
+        assert names.parse(names.phase_name("exchange")) == \
+            {"type": "phase", "phase": "exchange", "label": ""}
+        assert names.parse(names.phase_name("health")) == \
+            {"type": "phase", "phase": "health", "label": ""}
+        # the health quantities keep their own grammar
+        assert names.parse(names.health_name("delta", "l0"))["type"] == \
+            "health"
+        assert names.parse(names.host_name("loss_sync")) == \
+            {"type": "host", "span": "loss_sync"}
+        assert names.parse("lags/host/nap") is None
+        with pytest.raises(ValueError):
+            names.phase_name("bwd")
+        with pytest.raises(ValueError):
+            names.host_name("nap")
+
+
+#: op_name metadata of compiled ops (the spellings jax 0.9 gives) -> phase
+PHASE_CASES = [
+    ("jit(step)/lags/fwd/jvp()/while/body/closed_call/dot_general", "fwd"),
+    ("jit(step)/lags/fwd/transpose(jvp())/while/body/closed_call/"
+     "dot_general", "bwd"),
+    # remat recompute sits under transpose( too
+    ("jit(step)/lags/fwd/transpose(jvp())/while/body/checkpoint/"
+     "rematted_computation/exp", "bwd"),
+    ("jit(step)/jvp(lags/fwd)/mul", "fwd"),
+    ("jit(step)/transpose(jvp(lags/fwd))/mul", "bwd"),
+    # a wave tap's exchange runs inside the backward pass: not bwd
+    ("jit(step)/lags/fwd/transpose(lags/fwd)/jvp(lags/exchange)/mul",
+     "exchange"),
+    ("jit(step)/lags/fwd/transpose(lags/fwd)/jvp(lags/exchange)/"
+     "lags/select/l3/argmax", "select"),
+    ("jit(step)/lags/exchange/lags/scatter_mean/l12/scatter-add",
+     "scatter_mean"),
+    ("jit(step)/lags/exchange/lags/comm/flat/allgather/blocks"
+     "?nbytes=40&p=1/all_gather", "exchange"),
+    ("jit(step)/lags/comm/flat/allreduce/l0?nbytes=4&p=2/psum", None),
+    ("jit(step)/lags/apply/sub", "apply"),
+    ("jit(step)/lags/health/reduce_sum", "health"),
+    ("jit(step)/add", None),
+    ("", None),
+]
+
+
+@pytest.mark.parametrize("op_name,phase", PHASE_CASES)
+def test_phase_of(op_name, phase):
+    assert names.phase_of(op_name) == phase
+    assert names.step_phase(names.phase_of(op_name)) in \
+        names.STEP_PHASES + (None,)
+
 
 # ---------------------------------------------------------------------------
 # fake backend + trace container
@@ -119,87 +168,6 @@ class TestFakeTrace:
         kinds = {names.parse(e.name)["kind"]
                  for e in sparse.named(names.COMM_PREFIX)}
         assert "allgather" in kinds
-
-    def test_chrome_export_roundtrips_fake_trace(self, tmp_path):
-        """export_chrome_trace is the inverse of _parse_chrome_trace:
-        every grammar-named fake-backend event (step/fwd/bwd/comm)
-        survives with name, start and duration intact."""
-        tr = _fake().capture(0)
-        path = OT.export_chrome_trace(tr, str(tmp_path / "t.trace.json"))
-        got = OT._parse_chrome_trace(path)
-        want = [e for e in tr.events if names.parse(e.name) is not None]
-        assert want                      # the fake backend speaks grammar
-        assert [e.name for e in got] == [e.name for e in want]
-        for g, w in zip(got, want):
-            assert g.t_start == pytest.approx(w.t_start, abs=1e-12)
-            assert g.dur == pytest.approx(w.dur, abs=1e-12)
-
-    def test_chrome_export_gzip_and_meta(self, tmp_path):
-        import gzip
-        import json as J
-        tr = _fake().capture(1)
-        path = OT.export_chrome_trace(tr, str(tmp_path / "t.json.gz"))
-        with gzip.open(path, "rt") as f:
-            obj = J.load(f)
-        assert obj["otherData"] == tr.meta      # provenance rides along
-        assert all(ev["ph"] == "X" for ev in obj["traceEvents"])
-        cats = {ev["cat"] for ev in obj["traceEvents"]}
-        assert {"step", "fwd", "bwd", "comm"} <= cats
-        assert OT._parse_chrome_trace(path)     # .gz parse works too
-
-    def test_real_capture_smoke(self, tmp_path):
-        """jax.profiler capture wrapper: runs, returns a Trace, points at
-        the artifact dir even when nothing is parseable on a CPU host,
-        and reports which decoder (if any) produced the events."""
-        try:
-            tr = OT.capture_jax_trace(lambda x: jnp.sum(x * x),
-                                      jnp.arange(8.0),
-                                      log_dir=str(tmp_path), steps=2)
-        except Exception as e:           # pragma: no cover - env-specific
-            pytest.skip(f"jax.profiler unavailable here: {e}")
-        assert tr.meta["trace_dir"] == str(tmp_path)
-        assert tr.meta["steps"] == 2
-        assert tr.meta["decoder"] in ("chrome", "xplane", "none")
-        assert tr.meta["parsed"] == (tr.meta["decoder"] != "none")
-
-    def test_decode_xplane_absent_plugin_is_empty(self, tmp_path,
-                                                  monkeypatch):
-        monkeypatch.setattr(OT, "_xplane_converter", lambda: None)
-        (tmp_path / "host.xplane.pb").write_bytes(b"\x00")
-        assert OT.decode_xplane(str(tmp_path)) == []
-
-    def test_decode_xplane_via_fake_plugin(self, tmp_path, monkeypatch):
-        """XPlane protos route through the (monkeypatched) TensorBoard
-        converter into the same grammar filter as a chrome trace — and
-        tolerate the newer plugin's (data, mimetype) return shape."""
-        import json as J
-        chrome = J.dumps({"traceEvents": [
-            {"name": names.bwd_name("layers/0/w"), "ph": "X",
-             "ts": 10.0, "dur": 2000.0},
-            {"name": "xla_op_fusion.3", "ph": "X", "ts": 0, "dur": 5},
-        ]})
-        seen = []
-
-        def fake_convert(paths, tool, params):
-            seen.append((tuple(paths), tool))
-            return (chrome, "application/json")
-
-        monkeypatch.setattr(OT, "_xplane_converter",
-                            lambda: fake_convert)
-        sub = tmp_path / "plugins" / "profile"
-        sub.mkdir(parents=True)
-        (sub / "host.xplane.pb").write_bytes(b"\x00")
-        events = OT.decode_xplane(str(tmp_path))
-        assert seen and seen[0][1] == "trace_viewer"
-        assert [e.name for e in events] == [names.bwd_name("layers/0/w")]
-        assert events[0].dur == pytest.approx(2e-3)
-
-    def test_decode_xplane_bad_proto_skipped(self, tmp_path, monkeypatch):
-        def boom(paths, tool, params):
-            raise RuntimeError("corrupt proto")
-        monkeypatch.setattr(OT, "_xplane_converter", lambda: boom)
-        (tmp_path / "host.xplane.pb").write_bytes(b"\x00")
-        assert OT.decode_xplane(str(tmp_path)) == []
 
 
 # ---------------------------------------------------------------------------
