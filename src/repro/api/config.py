@@ -65,7 +65,11 @@ class RunConfig:
     # masked-argmax HLO) or "kernel" (the Pallas TPU kernels in
     # repro.kernels — fused accumulate+select+payload-pack where
     # available; interpret mode off-TPU).  Resolved per compressor via
-    # core.compressors.KERNEL_BACKED at build time.
+    # core.compressors.KERNEL_BACKED at build time on the simulation
+    # surface, where not every kernel-backed compressor is exact.  On a
+    # TPU the distributed block exchange (lags_dp) selects with k_b <= 256
+    # in the fused kernel under either value, bitwise equal to "xla"
+    # (BlockLAGSExchange._fused, core.lags.FUSED_MAX_KB).
     selection_backend: str = "xla"
     # inner-tier (intra-pod) compressor override for "lags_hier2"; None =
     # same as ``compressor``.  The inner tier selects on each worker's

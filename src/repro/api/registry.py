@@ -276,7 +276,9 @@ def _lags_factory(spec: ExchangeSpec):
     collective-free under GSPMD.  ``selection_backend="kernel"`` swaps
     the Pallas kernels in on BOTH surfaces: the sim compressor resolves
     through ``compressors.KERNEL_BACKED`` and the distributed block
-    exchange runs the fused select+EF+pack kernel (``use_kernel``).
+    exchange runs the fused select+EF+pack kernel (``use_kernel``).  On a
+    TPU the distributed block exchange runs that kernel for k_b <= 256
+    under "xla" too, with the same result bit for bit.
     """
     ks = spec.resolved_ks()
     if spec.sim:

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import compressors as C
+from repro.kernels import ops as kops
 
 
 # ---------------------------------------------------------------------------
@@ -471,6 +472,15 @@ class SLGSExchange:
 # Block-LAGS: the production distributed path.
 # ---------------------------------------------------------------------------
 
+#: Largest per-block budget the TPU's manual block path selects in the
+#: fused pass: the largest measured.  On a v5e, over one Mistral-7B
+#: layer's leaves and its embedding (350 M f32 parameters), the whole
+#: exchange took 24.8 / 85.8 / 107.2 / 158.1 / 304.0 / 608.5 ms fused
+#: against 46.0 / 156.3 / 347.7 / 363.9 / 512.7 / 808.2 ms in XLA at k_b
+#: 5 / 32 / 41 / 64 / 128 / 256; both grow about linearly in k_b there.
+FUSED_MAX_KB = 256
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockLAGSExchange:
     """LAGS with the block-budget compressor, keeping the (n_blocks,
@@ -552,24 +562,21 @@ class BlockLAGSExchange:
         return (jnp.stack(vals, axis=1).astype(rows.dtype),
                 jnp.stack(idx, axis=1))
 
+    def _fused(self, k_b: int, manual: bool) -> bool:
+        """Whether the select runs as the fused Pallas pass
+        (``kops.ef_select_pack_leaf``): always under ``use_kernel``; on a
+        TPU also on the manual path for k_b <= ``FUSED_MAX_KB``, where it
+        is bitwise equal to the XLA branch and reads the leaf once
+        instead of making k_b passes over it in HBM (or a
+        ``lax.top_k``)."""
+        return self.use_kernel or (manual and k_b <= FUSED_MAX_KB
+                                   and kops.on_tpu())
+
     def _local_rows(self, u_flat, e_flat, n_blocks, bs, k_b):
-        """Accumulate + select on the padded block view.
+        """Accumulate + select on the padded block view, in XLA.
 
         Returns (vals, local, residual_rows)."""
         pad = n_blocks * bs - u_flat.shape[0]
-        if self.use_kernel:
-            # fused Pallas path: accumulate + select + payload pack +
-            # residual in ONE pass over the (n_blocks, bs) view — acc
-            # never materializes in HBM.  Updates arrive pre-scaled
-            # (u = lr·g), so lr=1 here; bitwise-identical (vals, local,
-            # residual) to the XLA branch below.
-            from repro.kernels import ops as kops
-            g_rows = self._pin_rows(
-                jnp.pad(u_flat, (0, pad)).reshape(n_blocks, bs))
-            e_rows = self._pin_rows(
-                jnp.pad(e_flat, (0, pad)).reshape(n_blocks, bs))
-            return kops.ef_select_pack_rows(g_rows, e_rows, 1.0, None, k_b,
-                                            row_axes=self.row_axes)
         acc = e_flat + u_flat.astype(e_flat.dtype)
         rows = self._pin_rows(jnp.pad(acc, (0, pad)).reshape(n_blocks, bs))
         vals, local = self._select_rows(rows, k_b)
@@ -635,16 +642,28 @@ class BlockLAGSExchange:
                 return flat.reshape(param_shape)
             return flat.reshape(perm_shape).transpose(inv_perm)
 
+        fused = self._fused(k_b, manual=axis_names is not None)
+
+        def select(uu, ee):
+            """(vals, local, residual) of one worker's leaf."""
+            if fused:
+                # accumulate + select + payload pack + residual in one
+                # pass; acc never materializes in HBM
+                def lead(x):
+                    return x.transpose(perm) if perm else x
+                vals, local, resid = kops.ef_select_pack_leaf(
+                    lead(uu), lead(ee), k_b, bs, row_axes=self.row_axes)
+                return vals, local, (resid.transpose(inv_perm) if perm
+                                     else resid)
+            vals, local, resid_rows = self._local_rows(
+                to_flat(uu), to_flat(ee), n_blocks, bs, k_b)
+            return vals, local, from_flat(resid_rows.reshape(-1)[:size])
+
         if axis_names is None:
             # simulation path: leading (P,) axis
             p = u.shape[0]
-
-            def worker(uu, ee):
-                return self._local_rows(to_flat(uu), to_flat(ee),
-                                        n_blocks, bs, k_b)
-
             with _phase_scope("select", label):
-                vals, local, resid_rows = jax.vmap(worker)(u, e)
+                vals, local, resid = jax.vmap(select)(u, e)
             # aggregate: (P, n_blocks, k_b) -> per-row scatter-add
             with _phase_scope("scatter_mean", label):
                 idx_cat = jnp.moveaxis(local, 0, 1).reshape(n_blocks,
@@ -654,15 +673,11 @@ class BlockLAGSExchange:
                     jnp.zeros((n_blocks, bs), vals.dtype)) \
                     .at[row_ids, idx_cat].add(val_cat) / p
                 mean = from_flat(mean_rows.reshape(-1)[:size])
-            with _phase_scope("select", label):
-                resid = jax.vmap(
-                    lambda r: from_flat(r.reshape(-1)[:size]))(resid_rows)
             return mean.astype(u.dtype), resid
 
         axes = tuple(axis_names)
         with _phase_scope("select", label):
-            vals, local, resid_rows = self._local_rows(
-                to_flat(u), to_flat(e), n_blocks, bs, k_b)
+            vals, local, resid = select(u, e)
         if axes:
             # layer-wise sparse all-gather: 2*k_b scalars per block per worker
             with _comm_scope("flat", "allgather", "blocks",
@@ -681,8 +696,6 @@ class BlockLAGSExchange:
                 jnp.zeros((n_blocks, bs), vals.dtype)) \
                 .at[row_ids, idx_cat].add(val_cat) / p
             mean = from_flat(mean_rows.reshape(-1)[:size])
-        with _phase_scope("select", label):
-            resid = from_flat(resid_rows.reshape(-1)[:size])
         return mean.astype(u.dtype), resid
 
 

@@ -474,3 +474,58 @@ print("OK kernel shard_map bitwise")
 """
     out = _run(script, n_dev=4)
     assert "OK kernel shard_map bitwise" in out
+
+
+@pytest.mark.slow
+def test_lags_dp_tpu_select_bitwise_on_split_rows_and_pipelines():
+    """The select a TPU runs by default (the fused pass, chosen by
+    ``kops.on_tpu``) against the XLA branch, in whole train steps on a
+    (data 2, model 2) host mesh, where the exchange splits each leaf's
+    rows over ``model`` (the experts of an MoE leaf, the transposed
+    output dim of a projection), for the monolithic exchange, the wave
+    taps and the one-step-delayed exchange: parameters, optimizer and
+    error-feedback state after two steps are bit for bit equal.  The
+    kernel runs in interpret mode."""
+    script = """
+import dataclasses
+import jax, numpy as np
+from repro import api, compat
+from repro.configs import base
+from repro.kernels import ops
+from repro.launch import mesh as M, train as TR, specs as SP
+
+cfg = dataclasses.replace(base.get_smoke_config("olmoe_1b_7b"),
+                          train_mode="lags_dp")
+mesh = M.make_host_mesh(data=2, model=2)
+batch = SP.concrete_batch(cfg, base.InputShape("t", 16, 8, "train"))
+pack, calls = ops._ef.ef_select_pack_pallas, []
+
+def spy(u, e, thr=None, *, interpret, **kw):
+    calls.append(u.shape)
+    return pack(u, e, thr, interpret=True, **kw)
+
+ops._ef.ef_select_pack_pallas = spy
+for pipeline in ("off", "wave", "async1"):
+    got = {}
+    for tpu in (False, True):
+        ops.on_tpu = lambda: tpu
+        calls.clear()
+        run = api.RunConfig(mode="lags_dp", lr=1.0, ratio=8.0, block_size=64,
+                            chunk=16, loss_chunk=16, donate=False,
+                            pipeline=pipeline)
+        step, _, _ = api.build_train_step(cfg, mesh, run)
+        state, _ = TR.init_state(cfg, mesh, pipeline=pipeline)
+        with compat.set_mesh(mesh):
+            for _ in range(2):
+                state, _ = step(state, batch)
+        got[tpu] = [np.atleast_1d(np.asarray(x))
+                    for x in jax.tree.leaves(state)]
+        assert bool(calls) == tpu, (pipeline, tpu, len(calls))
+    assert len(got[False]) == len(got[True])
+    for a, b in zip(got[False], got[True]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert (a.view(np.uint8) == b.view(np.uint8)).all(), pipeline
+    print("OK", pipeline, len(calls))
+"""
+    out = _run(script, n_dev=4)
+    assert out.count("OK ") == 3

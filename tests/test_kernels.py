@@ -93,25 +93,52 @@ class TestEfSparsify:
 
 class TestEfSelectPack:
     """Fused select -> residual-update -> payload-pack kernel vs the
-    pure-jnp oracle.  The bitwise contract is pinned at lr=1.0 — the
-    production call (exchanges pass pre-scaled updates) — because
-    interpret-mode Pallas contracts ``e + lr*g`` into one fma for other
-    lr values (1-ulp vs XLA's separate mul+add; fma(1,g,e) == g+e)."""
+    pure-jnp oracle, bit for bit.  The wrapper rounds ``lr*g`` before the
+    kernel adds it (interpret-mode Pallas would contract ``e + lr*g``
+    into one fma, 1 ulp off XLA's separate mul+add); the exchanges pass
+    pre-scaled updates at lr=1, where nothing is scaled."""
 
-    @pytest.mark.parametrize("shape", [(1, 64), (7, 256), (8, 512), (3, 130)])
+    @pytest.mark.parametrize("shape", [
+        (1, 64), (7, 256), (8, 512), (3, 130),
+        # (rows, cols, block_size, k, lr, fill) at the TPU's geometries
+        pytest.param((1, 4096, 4096, 5, 1.0, "normal"), id="norm-leaf"),
+        pytest.param((13, 4096, 4096, 5, 1.0, "normal"), id="rows13"),
+        pytest.param((40, 4096, 4096, 1, 1.0, "ties"), id="rows40-k1"),
+        pytest.param((20, 4096, 4096, 4, 0.3, "zeros"), id="k4-lr"),
+        pytest.param((9, 4096, 4096, 32, 1.0, "ties"), id="k32"),
+        pytest.param((320, 128, 4096, 5, 1.0, "zeros"), id="lanes128"),
+        pytest.param((72, 128, 1024, 4, 0.3, "ties"), id="lanes128-lr"),
+        pytest.param((16, 1792, 512, 3, 1.0, "ties"), id="rows-of-3.5"),
+        pytest.param((10, 640, 256, 5, 1.0, "zeros"), id="rows-of-2.5"),
+    ])
     @pytest.mark.parametrize("dtype", DTYPES)
     @pytest.mark.parametrize("thr", [None, 0.5])
     def test_bitwise_oracle_at_unit_lr(self, shape, dtype, thr):
-        n, bs = shape
-        k = max(1, bs // 8)
-        g = jax.random.normal(jax.random.PRNGKey(n * bs), shape, dtype)
-        e = jax.random.normal(jax.random.PRNGKey(n * bs + 1), shape,
+        """``shape`` is (rows, bs) with k = bs/8, or a full case: a
+        (rows, cols) view cut into blocks of ``block_size`` (rows that do
+        not fill the kernel's tiles; 128-lane rows and rows of several
+        blocks or of a block and a half read in place), with planted ties
+        of equal magnitude and opposite sign, or all-zero rows."""
+        n, cols, bs, k, lr, fill = (
+            shape if len(shape) == 6
+            else (*shape, shape[1], max(1, shape[1] // 8), 1.0, "normal"))
+        g = jax.random.normal(jax.random.PRNGKey(n * cols), (n, cols), dtype)
+        e = jax.random.normal(jax.random.PRNGKey(n * cols + 1), (n, cols),
                               jnp.float32)
-        v, i, r = ops.ef_select_pack_rows(g, e, 1.0, thr, k)
-        vr, ir, rr = ref.ef_select_pack_ref(g, e, 1.0, thr, k)
+        if fill == "ties":
+            # 2.5 and -2.5 (exact in bf16) tie at the top of every block
+            g = g.at[:, 3].set(2.5).at[:, 7].set(-2.5).at[:, 9].set(-2.5)
+            e = e.at[:, 3].set(0.0).at[:, 7].set(0.0).at[:, 9].set(0.0)
+        elif fill == "zeros":
+            g = g.at[: n // 2].set(0.0)
+            e = e.at[: n // 2].set(0.0)
+        v, i, r = ops.ef_select_pack_rows(g, e, lr, thr, k, block_size=bs)
+        vr, ir, rr = ref.ef_select_pack_ref(g.reshape(-1, bs),
+                                            e.reshape(-1, bs), lr, thr, k)
         np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
         np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(rr))
+        np.testing.assert_array_equal(np.asarray(r).reshape(-1, bs),
+                                      np.asarray(rr))
 
     def test_nonunit_lr_allclose(self, rng):
         g = jax.random.normal(rng, (5, 256))

@@ -282,15 +282,58 @@ class TestKernelBackendParity:
             (jax.tree.unflatten(treedef, means),
              jax.tree.unflatten(treedef, efs)))
 
-    def test_block_lags_use_kernel_bitwise(self, rng):
-        u = _tree(rng)
-        ks = lags.ks_from_ratio(_unstacked(u), 4.0)
-        ex_x = lags.BlockLAGSExchange(ks=ks, block_size=32)
-        ex_k = lags.BlockLAGSExchange(ks=ks, block_size=32,
-                                      use_kernel=True)
-        ef0 = ex_x.init(u)
-        self._assert_bitwise(ex_x.exchange(u, ef0, None),
-                             ex_k.exchange(u, ef0, None))
+    @pytest.mark.parametrize("surface,ratio,on_tpu,use_kernel,fused,cut", [
+        ("sim", 4.0, False, True, True, None),   # selection_backend="kernel"
+        ("manual", 16.0, True, False, True, None),  # TPU default: k_b 16
+        ("manual", 2.0, True, False, True, None),   # k_b 128: lax.top_k in XLA
+        ("manual", 1.0, True, False, False, 128),   # k_b 256 above the cut
+        ("sim", 16.0, True, False, False, None),  # leading-P simulation path
+        ("manual", 16.0, False, False, False, None),  # the CPU
+    ])
+    def test_block_lags_use_kernel_bitwise(self, rng, monkeypatch, surface,
+                                           ratio, on_tpu, use_kernel,
+                                           fused, cut):
+        """The block exchange selects through the fused pass under
+        ``use_kernel``, and by default on a TPU's manual path for k_b <=
+        ``FUSED_MAX_KB``; wherever it does, means and residuals are bit
+        for bit the XLA branch's.  The manual leaves take the in-place views (rows
+        that are blocks, 128-lane rows, rows of 2.5 blocks) and the
+        padded block view."""
+        if surface == "sim":
+            u, bs, axes = _tree(rng), 32, None
+            ks = lags.ks_from_ratio(_unstacked(u), ratio)
+        else:
+            keys = jax.random.split(rng, 4)
+            u = {"rows": jax.random.normal(keys[0], (6, 256)),
+                 "lanes": jax.random.normal(keys[1], (4, 4, 128)),
+                 "wide": jax.random.normal(keys[2], (8, 640)),
+                 "odd": jax.random.normal(keys[3], (5, 300))}
+            bs, axes = 256, ()
+            ks = lags.ks_from_ratio(u, ratio)
+        ef0 = jax.tree.map(
+            lambda x: 0.5 * jax.random.normal(jax.random.fold_in(rng, x.size),
+                                              x.shape), u)
+        ex_x = lags.BlockLAGSExchange(ks=ks, block_size=bs)
+        want = ex_x.exchange(u, ef0, axes)
+        calls = []
+        pack = lags.kops._ef.ef_select_pack_pallas
+
+        def spy(u, e, thr=None, *, interpret, **kw):
+            # the program the backend gets, the kernel in interpret mode
+            calls.append(kw.get("block_size"))
+            return pack(u, e, thr, interpret=True, **kw)
+
+        monkeypatch.setattr(lags.kops._ef, "ef_select_pack_pallas", spy)
+        monkeypatch.setattr(lags.kops, "on_tpu", lambda: on_tpu)
+        if cut is not None:
+            monkeypatch.setattr(lags, "FUSED_MAX_KB", cut)
+        ex_k = lags.BlockLAGSExchange(ks=ks, block_size=bs,
+                                      use_kernel=use_kernel)
+        self._assert_bitwise(want, ex_k.exchange(u, ef0, axes))
+        assert bool(calls) == fused
+        if fused and surface == "manual":
+            # in place: "rows", "lanes" and "wide"; block view: "odd"
+            assert sorted(calls, key=str) == [256, 256, 256, None]
 
     def test_slgs_kernel_bitwise(self, rng):
         u = _tree(rng)
