@@ -18,6 +18,8 @@ reference computes the configuration as run.
 ``prec="fp8"`` is the control: every matrix product takes its operands,
 and its backward pass its cotangent, rounded to float8 e4m3 with one
 scale per tensor.
+
+``flops_per_token`` counts the model's work for ``step_mfu``.
 """
 from __future__ import annotations
 
@@ -190,3 +192,29 @@ def head_loss(w, x, labels, cfg: dict, prec: str = "f32", chunk: int = 512):
 def aux_weight(cfg: dict) -> float:
     return float(cfg.get(AUX_KEY, 0.0)) if int(cfg.get("num_experts", 0)) \
         else 0.0
+
+
+def flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Matrix-multiply FLOP of the forward and backward passes (3x the
+    forward) per trained token.  Forward per layer: the q/k/v/o
+    projections, causal attention (QK^T and PV over the ``S/2`` earlier
+    positions on average: ``2 * S * H * hd``), and the FFN (three
+    matrices) or, for a mixture of experts, the router and the ``top_k``
+    experts a token is sent to; then the output head.  The embedding
+    lookup, norms, softmax and recomputation are not counted."""
+    d = int(cfg["hidden_size"])
+    h = int(cfg["num_attention_heads"])
+    kv = int(cfg["num_key_value_heads"])
+    hd = int(cfg["head_dim"])
+    f = int(cfg["intermediate_size"])
+    v = int(cfg["vocab_size"])
+    n_layers = int(cfg["num_hidden_layers"])
+    proj = 2 * d * hd * (h + 2 * kv) + 2 * h * hd * d
+    attn = 2 * seq_len * h * hd
+    e = int(cfg.get("num_experts", 0))
+    if e:
+        ffn = 2 * d * e + int(cfg["num_experts_per_tok"]) * 3 * 2 * d * f
+    else:
+        ffn = 3 * 2 * d * f
+    head = 2 * d * v
+    return 3.0 * (n_layers * (proj + attn + ffn) + head)

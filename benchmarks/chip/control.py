@@ -6,8 +6,9 @@ seeds, and the control's and the planted faults' numbers on a few.
 
 One process builds the cell's program once; for every seed it runs the
 program's first steps (the timed path, as a run does) and the float32
-reference, and prints the three compared numbers.  For each control
-seed it also puts the reference in the program's place:
+reference, and prints the compared numbers.  For each control
+seed it also puts the reference in the program's place, and reads the
+same numbers, the configuration's own among them (``check.numbers``):
 
 - ``control``: the reference computed in float8 (e4m3, one scale per
   tensor), the precision below the configuration's bfloat16;
@@ -19,9 +20,9 @@ seed it also puts the reference in the program's place:
 A step that returns its state unchanged reads 1 on both gradient and
 change numbers by their definition and needs no run.  Each row keeps
 the per-leaf readings (``grad_norms``, ``change_norms``; the program's
-row the reference's too, under ``ref``), so that other numbers can be
-worked out from them afterwards.  The benchmark's own runs never run
-this script.  Exits 2 without a TPU.
+row the reference's too, under ``ref``) and each step's ``history``
+entry, so that other numbers can be worked out from them afterwards.
+The benchmark's own runs never run this script.  Exits 2 without a TPU.
 """
 import time
 
@@ -47,6 +48,7 @@ def variants(chips: int) -> list:
 
 def readings_for_seed(prog, seed: int, registry, with_control: bool) -> list:
     from lagsbench import check
+    config = prog.cell["config"]
     batches = prog.batches(seed)
     k = int(prog.traffic["check_steps"])
     t = time.perf_counter()
@@ -58,7 +60,7 @@ def readings_for_seed(prog, seed: int, registry, with_control: bool) -> list:
     ref = prog.reference(seed, batches[:k])
     t_ref = time.perf_counter() - t
     rows = [{"seed": seed, "who": "program", "prog_s": t_prog,
-             "ref_s": t_ref, **check.numbers(got, ref), **got,
+             "ref_s": t_ref, **check.numbers(got, ref, config), **got,
              "ref": ref}]
     if with_control:
         for who, prec, fault in variants(prog.chips):
@@ -66,8 +68,15 @@ def readings_for_seed(prog, seed: int, registry, with_control: bool) -> list:
             alt = prog.reference(seed, batches[:k], prec=prec, fault=fault)
             rows.append({"seed": seed, "who": who,
                          "ref_s": time.perf_counter() - t,
-                         **check.numbers(alt, ref), **alt})
+                         **check.numbers(alt, ref, config), **alt})
     return rows
+
+
+def _plain(x):
+    """JSON for the arrays a reference's ``blocks`` readings hold."""
+    if hasattr(x, "tolist"):
+        return x.tolist()
+    raise TypeError(f"{type(x).__name__} is not JSON serializable")
 
 
 def main(argv=None) -> int:
@@ -95,7 +104,7 @@ def main(argv=None) -> int:
         for s in (int(x) for x in args.seeds.split(",")):
             for row in readings_for_seed(prog, s, registry, s in ctl):
                 row["workload"] = args.workload
-                line = json.dumps(row)
+                line = json.dumps(row, default=_plain)
                 print(line, flush=True)
                 if out is not None:
                     out.write(line + "\n")

@@ -12,12 +12,24 @@ Three numbers compare the program's first steps with the reference's:
   thousandth of the median leaf's move by rounding alone and are left
   out of it.
 
-A run is correct when each number is at or under its limit
-(``limits/<cell>.json``).
+A configuration adds numbers of its own through its reference module
+(``configs/<reference>.py``): an optional ``numbers(prog, ref)`` returns
+more of them, by name, from the same readings; ``prog["history"]``
+holds the program's ``Session.run`` history entry of each checked step,
+``ref["history"]`` the reference's (the step's loss, and under
+``blocks`` what its ``block`` returned per layer and worker beyond the
+layer's output and load-balance term).  Where the reference stands in
+the program's place (``control.py``), ``prog`` is a reference's
+readings.
+
+A run is correct when each number its limits name (``limits/<cell>.json``)
+was produced and is at or under its limit.
 """
 from __future__ import annotations
 
 import statistics
+
+from lagsbench import reference
 
 #: A leaf whose reference gradient norm is under this share of the
 #: median leaf's is not counted in ``change_norm_gap``.
@@ -53,24 +65,35 @@ def moving_leaves(ref_grads: dict) -> list:
     return [n for n, g in ref_grads.items() if g >= STILL_LEAF * med]
 
 
-def numbers(prog: dict, ref: dict) -> dict:
+def numbers(prog: dict, ref: dict, config: dict) -> dict:
     """The compared numbers from the program's and the reference's
-    readings (``losses``, ``grad_norms``, ``change_norms``)."""
+    readings (``losses``, ``grad_norms``, ``change_norms``, ``history``):
+    the three above, then those of ``config``'s reference module."""
     moving = moving_leaves(ref["grad_norms"])
     g, g_at = norm_gap(prog["grad_norms"], ref["grad_norms"])
     c, c_at = norm_gap(prog["change_norms"], ref["change_norms"], moving)
-    return {"loss_gap": loss_gap(prog["losses"], ref["losses"]),
-            "grad_norm_gap": g, "change_norm_gap": c,
-            "_worst_leaf": {"grad_norm_gap": g_at, "change_norm_gap": c_at}}
+    out = {"loss_gap": loss_gap(prog["losses"], ref["losses"]),
+           "grad_norm_gap": g, "change_norm_gap": c}
+    more = getattr(reference.model_module(config), "numbers", None)
+    if more is not None:
+        for name, v in more(prog, ref).items():
+            if name in out or name.startswith("_"):
+                raise ValueError(f"configs/{config['reference']}.py "
+                                 f"numbers() gives {name!r}, a name it "
+                                 f"may not take")
+            out[name] = v
+    out["_worst_leaf"] = {"grad_norm_gap": g_at, "change_norm_gap": c_at}
+    return out
 
 
 def judge(nums: dict, limits: dict) -> tuple[bool, dict]:
-    """(correct, {name: {"value", "limit"}}); a NaN fails."""
+    """(correct, {name: {"value", "limit"}}); a NaN fails, and so does a
+    limit on a number that nothing produced (its value ``None``)."""
     report = {}
     ok = True
     for name, lim in limits.items():
-        v = nums[name]
+        v = nums.get(name)
         report[name] = {"value": v, "limit": lim}
-        if not v <= lim:
+        if v is None or not v <= lim:
             ok = False
     return ok, report

@@ -2,12 +2,10 @@
 shapes alone (never from the compiled program).
 
 ``model_flops_per_token``: matrix-multiply FLOP of the forward and
-backward passes (3x the forward) per trained token.  Forward per layer:
-the q/k/v/o projections, causal attention (QK^T and PV over the
-``S/2`` earlier positions on average: ``2 * S * H * hd``), and the FFN
-(three matrices) or, for a mixture of experts, the router and the
-``top_k`` experts a token is sent to; then the output head.  The
-embedding lookup, norms, softmax and recomputation are not counted.
+backward passes per trained token, as the configuration's reference
+module (``configs/<reference>.py``) counts them in its
+``flops_per_token(config, seq_len)``; a module without one is an error,
+since a wrong ``step_mfu`` is worse than none.
 
 ``exchange_min_bytes``: the least HBM traffic of one worker's LAGS
 exchange per step, whatever implements it: read the gradient (parameter
@@ -17,24 +15,17 @@ payload, and write each gathered entry into the float32 update.
 """
 from __future__ import annotations
 
+from lagsbench import reference
+
 
 def model_flops_per_token(config: dict, seq_len: int) -> float:
-    d = int(config["hidden_size"])
-    h = int(config["num_attention_heads"])
-    kv = int(config["num_key_value_heads"])
-    hd = int(config["head_dim"])
-    f = int(config["intermediate_size"])
-    v = int(config["vocab_size"])
-    n_layers = int(config["num_hidden_layers"])
-    proj = 2 * d * hd * (h + 2 * kv) + 2 * h * hd * d
-    attn = 2 * seq_len * h * hd
-    e = int(config.get("num_experts", 0))
-    if e:
-        ffn = 2 * d * e + int(config["num_experts_per_tok"]) * 3 * 2 * d * f
-    else:
-        ffn = 3 * 2 * d * f
-    head = 2 * d * v
-    return 3.0 * (n_layers * (proj + attn + ffn) + head)
+    module = reference.model_module(config)
+    count = getattr(module, "flops_per_token", None)
+    if count is None:
+        raise AttributeError(
+            f"configs/{config['reference']}.py has no flops_per_token("
+            f"config, seq_len): the configuration has no FLOP count")
+    return count(config, seq_len)
 
 
 def model_flops_per_step(config: dict, seq_len: int, tokens: int) -> float:

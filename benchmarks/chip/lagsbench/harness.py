@@ -67,7 +67,7 @@ class Program:
         self.cell = cell
         self.chips = cell["chips"]
         self.traffic = cell["traffic"]
-        self.cfg = spec.model_config(cell["config"])
+        self.cfg = spec.model_config(cell["config"], cell.get("config_file"))
         self.run_cfg = api.RunConfig(**self.traffic["run"])
         self.mesh = compat.make_mesh((self.chips, 1), ("data", "model"),
                                      devices=devices)
@@ -103,30 +103,32 @@ class Program:
                          self.rows)
 
     def step(self, state, batch, registry):
-        """One step through ``Session.run``; (state, loss)."""
+        """One step through ``Session.run``; (state, its history entry)."""
         import jax
         with jax.profiler.TraceAnnotation(SPAN_STEP):
             state, hist = self.session.run(
                 lambda t: _data(batch), 1, state=state, log_every=0,
                 metrics=registry)
-        return state, hist[0]["loss"]
+        return state, hist[0]
 
     def first_steps(self, seed: int, batches: list, registry):
         """Weights from the seed, then ``check_steps`` steps with the
-        readings the reference is held to.  Returns (state, readings)."""
+        readings the reference is held to, each step's whole history
+        entry among them.  Returns (state, readings)."""
         k = int(self.traffic["check_steps"])
         lr = float(self.traffic["run"]["lr"])
         state = self.start(seed)
-        losses, grads = [], None
+        history, grads = [], None
         for t in range(k):
-            state, loss = self.step(state, batches[t], registry)
-            losses.append(loss)
+            state, row = self.step(state, batches[t], registry)
+            history.append(row)
             if t == 0:
                 grads = readings.grad_norms(state, self.weights0(seed),
                                             self.lay, lr)
         changes = readings.change_norms(state, self.weights0(seed), self.lay)
-        return state, {"losses": losses, "grad_norms": grads,
-                       "change_norms": changes}
+        return state, {"losses": [row["loss"] for row in history],
+                       "grad_norms": grads, "change_norms": changes,
+                       "history": history}
 
     def reference(self, seed: int, batches: list, prec: str = "f32",
                   fault: str | None = None) -> dict:
@@ -221,10 +223,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     n, losses, step_s = 0, [], []
     while True:
         t = time.perf_counter()
-        state, loss = prog.step(state, batches[(k + n) % len(batches)],
-                                registry)
+        state, row = prog.step(state, batches[(k + n) % len(batches)],
+                               registry)
         step_s.append(time.perf_counter() - t)
-        losses.append(loss)
+        losses.append(row["loss"])
         n += 1
         if time.perf_counter() - t0 >= seconds:
             break
@@ -267,7 +269,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     t_ref = time.perf_counter()
     ref_read = prog.reference(seed, batches[:k])
-    nums = check.numbers(prog_read, ref_read)
+    nums = check.numbers(prog_read, ref_read, cell["config"])
     correct, report = check.judge(nums, cell["limits"])
     say(f"reference {time.perf_counter() - t_ref:.1f} s | losses program "
         f"{prog_read['losses']} reference {ref_read['losses']} | worst "

@@ -15,7 +15,11 @@ After the first step it reads, per named leaf, the norm of the gradient
 as the optimizer got it, worked out from the state: ``(mean over workers
 of the residual + (w0 - w1)) / lr``; after the last step, the norm of
 each leaf's change ``w - w0``.  :mod:`lagsbench.readings` reads the
-program's state by the same formulas.
+program's state by the same formulas.  Each step's ``history`` entry
+holds its loss and, where the configuration's ``block`` returns a third
+value (readings of its own, such as routing counts), those values per
+layer and worker under ``blocks``, for the module's ``numbers`` hook
+(:mod:`lagsbench.check`).
 """
 from __future__ import annotations
 
@@ -164,8 +168,9 @@ class Reference:
             for n, p in zip(names, new):
                 self.params[d][n] = p
 
-    def step(self, rows: list) -> float:
-        """One training step; rows[w] = {"tokens", "labels"} (numpy)."""
+    def step(self, rows: list) -> dict:
+        """One training step; rows[w] = {"tokens", "labels"} (numpy).
+        Returns its history entry."""
         nw, n_layers = self.n_workers, len(self.per_layer)
         toks = [jax.device_put(r["tokens"], d) for r, d in zip(rows, self.devs)]
         labs = [jax.device_put(_labels(r["labels"], self.fault), d)
@@ -174,11 +179,17 @@ class Reference:
         xs = [[self._f.embed_fwd(self._w(d, emb, False), t)]
               for d, t in zip(self.devs, toks)]
         aux = [jnp.zeros((), F32)] * nw
+        blocks = []
         for l in range(n_layers):
+            got = []
             for w, d in enumerate(self.devs):
-                y, a = self._f.fwd(self._w(d, self.per_layer[l], True), xs[w][-1])
+                y, a, *more = self._f.fwd(self._w(d, self.per_layer[l], True),
+                                          xs[w][-1])
                 xs[w].append(y)
                 aux[w] = aux[w] + a
+                got.extend(jax.device_get(more))
+            if got:
+                blocks.append(got)
         losses, dxs, grads = [], [], []
         for w, d in enumerate(self.devs):
             wh = self._w(d, self.head, False)
@@ -202,7 +213,10 @@ class Reference:
             for w in range(nw)]
         self._exchange(grads, emb, strip=False)
         self._flush()
-        return sum(losses) / nw
+        entry = {"loss": sum(losses) / nw}
+        if blocks:
+            entry["blocks"] = blocks
+        return entry
 
     # -- readings ---------------------------------------------------------------
     def grad_norms(self) -> dict:
@@ -247,7 +261,7 @@ def _functions(config: dict, run: dict, prec: str):
         return model.block(cast(ws), x, cfg, prec)
 
     def bwd(ws, x, dy, daux):
-        _, vjp = jax.vjp(lambda w, xx: model.block(w, xx, cfg, prec),
+        _, vjp = jax.vjp(lambda w, xx: model.block(w, xx, cfg, prec)[:2],
                          cast(ws), x)
         return vjp((dy, daux))
 
@@ -321,16 +335,16 @@ def follow(config: dict, traffic: dict, lay: tuple, seed: int, devices: list,
            n_workers: int, rows_per_step: list, prec: str = "f32",
            fault: str | None = None) -> dict:
     """Run the reference through ``len(rows_per_step)`` steps; returns
-    ``{"losses", "grad_norms", "change_norms"}``."""
+    ``{"losses", "grad_norms", "change_norms", "history"}``."""
     ref = Reference(config, traffic, lay, seed, devices, n_workers, prec,
                     fault)
-    losses, grads = [], None
+    history, grads = [], None
     for t, rows in enumerate(rows_per_step):
-        losses.append(ref.step(rows))
+        history.append(ref.step(rows))
         if t == 0:
             grads = ref.grad_norms()
-    out = {"losses": losses, "grad_norms": grads,
-           "change_norms": ref.change_norms()}
+    out = {"losses": [h["loss"] for h in history], "grad_norms": grads,
+           "change_norms": ref.change_norms(), "history": history}
     del ref
     return out
 

@@ -9,6 +9,7 @@ one of these loaders.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import pathlib
 
@@ -48,6 +49,7 @@ def load_cell(name: str, repo_dir: pathlib.Path = REPO_DIR) -> dict:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name])]
     return {"name": name, "chips": int(cell["chips"]), "config": config,
+            "config_file": cfg_entry["file"],
             "traffic": traffic, "limits": limits["limits"],
             "end_to_end": end_to_end, "per_layer": per_layer}
 
@@ -59,29 +61,62 @@ def as_run(config: dict) -> dict:
     return {**config, **config.get("as_run", {})}
 
 
-def model_config(config: dict):
+#: The program's ``ModelConfig`` fields a configuration file's source
+#: keys give, each worked out from the file only where ``program.model``
+#: does not state the field.
+DERIVED = {
+    "n_layers": lambda c: int(c["num_hidden_layers"]),
+    "d_model": lambda c: int(c["hidden_size"]),
+    "n_heads": lambda c: int(c["num_attention_heads"]),
+    "n_kv_heads": lambda c: int(c["num_key_value_heads"]),
+    "d_ff": lambda c: int(c["intermediate_size"]),
+    "vocab": lambda c: int(c["vocab_size"]),
+    "head_dim": lambda c: int(c["head_dim"]),
+    "activation": lambda c: c["hidden_act"],
+    "gated_ffn": lambda c: True,
+    "norm": lambda c: "rmsnorm",
+    "rope_theta": lambda c: float(c["rope_theta"]),
+    "sliding_window": lambda c: c.get("sliding_window"),
+    "tie_embeddings": lambda c: bool(c["tie_word_embeddings"]),
+    "n_experts": lambda c: int(c.get("num_experts", 0)),
+    "moe_top_k": lambda c: int(c.get("num_experts_per_tok", 0)),
+    "moe_period": lambda c: 1,
+    "moe_shard": lambda c: c["program"].get("moe_shard", "ffn"),
+    "dtype": lambda c: c["program"]["dtype"],
+    "param_dtype": lambda c: c["program"]["param_dtype"],
+    "source": lambda c: c["source"],
+}
+
+
+def model_config(config: dict, file: str | None = None):
     """The program's ``ModelConfig`` for a configuration file.
 
-    Every width comes from the file's source keys; the ``program`` block
-    gives what the source has no key for (family, compute types)."""
+    Every width comes from the file's source keys (``DERIVED``); the
+    ``program`` block gives what the source has no key for (family,
+    compute types), and its optional ``model`` dict sets ``ModelConfig``
+    fields by name over what is derived: the fields of an architecture
+    whose source keys the derivation does not know.  ``file`` names the
+    file in errors."""
     from repro.configs.base import ModelConfig
+    where = file or f"configuration {config['name']!r}"
     prog = config["program"]
-    n_experts = int(config.get("num_experts", 0))
-    return ModelConfig(
-        name=config["name"], family=prog["family"],
-        n_layers=int(config["num_hidden_layers"]),
-        d_model=int(config["hidden_size"]),
-        n_heads=int(config["num_attention_heads"]),
-        n_kv_heads=int(config["num_key_value_heads"]),
-        d_ff=int(config["intermediate_size"]),
-        vocab=int(config["vocab_size"]),
-        head_dim=int(config["head_dim"]),
-        activation=config["hidden_act"], gated_ffn=True, norm="rmsnorm",
-        rope_theta=float(config["rope_theta"]),
-        sliding_window=config.get("sliding_window"),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        n_experts=n_experts,
-        moe_top_k=int(config.get("num_experts_per_tok", 0)),
-        moe_period=1, moe_shard=prog.get("moe_shard", "ffn"),
-        dtype=prog["dtype"], param_dtype=prog["param_dtype"],
-        source=config["source"])
+    stated = prog.get("model", {})
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    for field in stated:
+        if field not in known:
+            raise ValueError(f"{where}: program.model field {field!r} is "
+                             f"no field of the program's ModelConfig")
+    fields = {"name": config["name"], "family": prog["family"]}
+    for field, derive in DERIVED.items():
+        if field in stated:
+            continue
+        try:
+            fields[field] = derive(config)
+        except KeyError as e:
+            raise KeyError(f"{where}: no source key {e.args[0]!r} for the "
+                           f"program's {field!r}, and program.model does "
+                           f"not state {field!r}") from None
+    for field, value in stated.items():
+        # JSON has no tuples; a ModelConfig is hashed, so no lists
+        fields[field] = tuple(value) if isinstance(value, list) else value
+    return ModelConfig(**fields)

@@ -1,5 +1,6 @@
 """Configuration files against their sources, and the harness's view of
 the program's parameters."""
+import dataclasses
 import json
 
 import jax
@@ -103,3 +104,66 @@ def test_layout_round_trips_the_program_tree():
     back = weights.to_program(named, lay, tree)
     for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(tree)):
         assert (a == b).all()
+
+
+def _parent_model_config(config: dict):
+    """The derivation before configuration files could state fields."""
+    from repro.configs.base import ModelConfig
+    prog = config["program"]
+    return ModelConfig(
+        name=config["name"], family=prog["family"],
+        n_layers=int(config["num_hidden_layers"]),
+        d_model=int(config["hidden_size"]),
+        n_heads=int(config["num_attention_heads"]),
+        n_kv_heads=int(config["num_key_value_heads"]),
+        d_ff=int(config["intermediate_size"]),
+        vocab=int(config["vocab_size"]),
+        head_dim=int(config["head_dim"]),
+        activation=config["hidden_act"], gated_ffn=True, norm="rmsnorm",
+        rope_theta=float(config["rope_theta"]),
+        sliding_window=config.get("sliding_window"),
+        tie_embeddings=bool(config["tie_word_embeddings"]),
+        n_experts=int(config.get("num_experts", 0)),
+        moe_top_k=int(config.get("num_experts_per_tok", 0)),
+        moe_period=1, moe_shard=prog.get("moe_shard", "ffn"),
+        dtype=prog["dtype"], param_dtype=prog["param_dtype"],
+        source=config["source"])
+
+
+def _config(name):
+    with open(BENCH_DIR / "configs" / f"{name}.json") as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", ["mistral-7b", "olmoe-1b-7b"])
+def test_model_config_is_the_derivation_field_for_field(name):
+    cfg = _config(name)
+    assert dataclasses.asdict(spec.model_config(cfg)) == \
+        dataclasses.asdict(_parent_model_config(cfg))
+
+
+def test_program_model_fields_reach_the_program():
+    cfg = _config("mistral-7b")
+    cfg["program"]["model"] = {"moe_period": 2, "d_ff": 1408,
+                               "xlstm_pattern": ["m", "s"]}
+    mc = spec.model_config(cfg)
+    assert (mc.moe_period, mc.d_ff, mc.xlstm_pattern) == (2, 1408, ("m", "s"))
+    assert mc.d_model == cfg["hidden_size"]
+    hash(mc)                    # the program keys a shape cache on it
+
+
+def test_unknown_program_model_field_raises_and_names_it():
+    cfg = _config("mistral-7b")
+    cfg["program"]["model"] = {"n_routed_expert": 64}
+    with pytest.raises(ValueError, match="n_routed_expert") as e:
+        spec.model_config(cfg, "benchmarks/chip/configs/x.json")
+    assert "benchmarks/chip/configs/x.json" in str(e.value)
+
+
+def test_missing_head_dim_raises_unless_program_model_states_it():
+    cfg = _config("mistral-7b")
+    del cfg["head_dim"]
+    with pytest.raises(KeyError, match="head_dim"):
+        spec.model_config(cfg)
+    cfg["program"]["model"] = {"head_dim": 192}
+    assert spec.model_config(cfg).head_dim == 192

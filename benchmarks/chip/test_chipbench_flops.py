@@ -56,3 +56,13 @@ def test_kept_per_leaf_small_leaf_keeps_its_share():
     # a 4096-wide norm is one block: k = round(4.096) = 4 entries
     assert flops.kept_per_leaf(4096, 1000.0, 4096) == 4
     assert flops.kept_per_leaf(100, 1000.0, 4096) == 1
+
+
+def test_reference_module_without_flops_per_token_raises(monkeypatch):
+    import types
+
+    from lagsbench import reference
+    monkeypatch.setattr(reference, "model_module",
+                        lambda config: types.SimpleNamespace())
+    with pytest.raises(AttributeError, match="flops_per_token"):
+        flops.model_flops_per_token(_config("mistral-7b"), 4096)

@@ -87,7 +87,8 @@ def test_control_in_float8_is_not_correct(limits, config, traffic):
     batches = prog.batches(SEED)
     ref = prog.reference(SEED, batches[:3])
     ctl = prog.reference(SEED, batches[:3], prec="fp8")
-    ok, report = check.judge(check.numbers(ctl, ref), cell["limits"])
+    ok, report = check.judge(check.numbers(ctl, ref, cell["config"]),
+                             cell["limits"])
     assert not ok, report
 
 
